@@ -38,6 +38,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sections = sys.argv[1:] or ["table5", "table7", "fig3", "table10", "heatmap", "kernel", "weighted", "query", "stream", "persist", "serve", "obs", "search", "roofline"]
     failures = 0
     for section in sections:

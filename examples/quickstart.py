@@ -10,8 +10,11 @@ again a bitmap, queries compose and feed back in as virtual columns.
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.bitmaps import unpack
 from repro.query import And, BitmapIndex, Col, Interval, Not, Parity, Threshold
+
+enable_compile_cache()
 
 N_STORES, N_PRODUCTS = 12, 10_000
 rng = np.random.default_rng(0)

@@ -21,8 +21,11 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import listalgos as LA
 from repro.search import build_qgram_index, edit_distance
+
+enable_compile_cache()
 
 Q = 2  # bigrams, as Ferro et al.
 rng = np.random.default_rng(0)

@@ -17,6 +17,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.ckpt import CheckpointManager
 from repro.configs import get_config
 from repro.data import DataConfig, lm_batch
@@ -25,6 +26,7 @@ from repro.train import OptConfig, TrainConfig, init_train_state, make_train_ste
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--width", type=int, default=128)

@@ -246,13 +246,16 @@ def measure_calibration(
     of ``repeats`` timed runs prices the words the planner's own model says
     the backend touches -- the constant is exactly the words→µs exchange
     rate that makes ``Plan.cost`` comparable across backends on THIS
-    device.  Runs in ~a second on CPU at the default shape.
+    device.  Runs in ~a second on CPU at the default shape.  ``fused`` is
+    skipped where the planner does not offer it (off the TPU); an error
+    from any backend that is measured propagates.
     """
     import jax
     import numpy as np
 
     from repro.core.planner import estimate_words_touched
     from repro.query import BitmapIndex, Threshold
+    from repro.query.index import _fused_available
 
     rng = np.random.default_rng(seed)
     # mixed-density columns so the tiled path has real dirty tiles to price
@@ -262,6 +265,8 @@ def measure_calibration(
     stats = idx.store.member_stats(None)
     calib = Calibration(device=device_signature())
     for backend in backends:
+        if backend == "fused" and not _fused_available():
+            continue  # the planner never offers the fused kernel here
         t = {"wide_or": 1, "wide_and": n}.get(backend, max(2, n // 2))
         q = Threshold(t)
         words = estimate_words_touched(
@@ -269,15 +274,12 @@ def measure_calibration(
         )
         if words is None:
             continue
-        try:
-            jax.block_until_ready(idx.execute(q, backend=backend))  # warm-up
-            times = []
-            for _ in range(repeats):
-                t0 = time.perf_counter()
-                jax.block_until_ready(idx.execute(q, backend=backend))
-                times.append(time.perf_counter() - t0)
-        except Exception:  # noqa: BLE001 -- a backend missing on this device
-            continue
+        jax.block_until_ready(idx.execute(q, backend=backend))  # warm-up
+        times = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(idx.execute(q, backend=backend))
+            times.append(time.perf_counter() - t0)
         med = sorted(times)[len(times) // 2]
         calib.us_per_kword[backend] = med * 1e6 * 1024.0 / float(words)
         calib.samples[backend] = repeats

@@ -16,11 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["pipeline_forward"]
 
 
@@ -50,8 +45,11 @@ def pipeline_forward(stage_fn, x, stage_params, mesh, axis_name: str = "pod"):
     def per_device(p, xs):
         w = jax.tree.map(lambda a: a[0], p)  # this device's stage
         idx = jax.lax.axis_index(axis_name)
-        buf = jnp.zeros_like(xs[0])
-        outs = jnp.zeros_like(xs)
+        # the carry turns device-varying after one step (it holds this
+        # stage's output), so the initial carry must be typed varying too
+        buf, outs = jax.lax.pcast(
+            (jnp.zeros_like(xs[0]), jnp.zeros_like(xs)), axis_name, to="varying"
+        )
 
         def step(carry, t):
             buf, outs = carry
@@ -65,7 +63,7 @@ def pipeline_forward(stage_fn, x, stage_params, mesh, axis_name: str = "pod"):
         (_, outs), _ = jax.lax.scan(step, (buf, outs), jnp.arange(m + n_stages - 1))
         return outs[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh, in_specs=(P(axis_name), P()), out_specs=P(axis_name)
     )
     return fn(stage_params, x)[-1]

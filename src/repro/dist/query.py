@@ -24,6 +24,10 @@ exactly what composes: sharded results feed back as sharded columns via
     installed, the whole query runs as one ``shard_map`` over the
     device-sharded word axis (the SPMD fast path); otherwise shards run
     host-sequenced, each on its own representation.
+  * With a mesh, shard ``i`` lives on the ``i``-th device along the mesh
+    axis: its dense view, container packs and results are placed there,
+    and the ``shard_map`` input is assembled from those per-device arrays
+    without a gather.
 
 An 8-device host-platform CPU mesh (``XLA_FLAGS=
 --xla_force_host_platform_device_count=8``) exercises the full path in CI.
@@ -63,18 +67,6 @@ _SPMD_BACKENDS = frozenset(
 )
 
 
-def _shard_map():
-    """The shard_map entrypoint across jax versions (None if unavailable)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    try:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-    except ImportError:  # pragma: no cover
-        return None
-
-
 # Jitted shard_map runners cached by circuit STRUCTURE (+ mesh/axis), like
 # kernels.threshold_ssum's structural jit cache: repeated queries -- the
 # serving admission loop above all -- trace and compile once per circuit
@@ -83,7 +75,21 @@ _SPMD_RUNNERS: dict = {}
 _SPMD_RUNNERS_CAP = 256
 
 
-def _spmd_runner(circuit, mesh, axis: str, n: int, spmd):
+def _gather(parts, axis: int = 0) -> jax.Array:
+    """Concatenate per-shard arrays on the default device: the explicit,
+    paid-for gather (placed shards live on different devices)."""
+    home = jax.devices()[0]
+    return jnp.concatenate([jax.device_put(p, home) for p in parts], axis=axis)
+
+
+def _block_devices(mesh, axis: str) -> list:
+    """The first device of each block along ``axis``: where a mesh places
+    shard ``i`` (the other mesh axes hold replicas of the block)."""
+    devs = np.moveaxis(mesh.devices, mesh.axis_names.index(axis), 0)
+    return list(devs.reshape(devs.shape[0], -1)[:, 0])
+
+
+def _spmd_runner(circuit, mesh, axis: str, n: int):
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.threshold_ssum import circuit_structural_key
@@ -99,7 +105,9 @@ def _spmd_runner(circuit, mesh, axis: str, n: int, spmd):
             return jnp.stack([jnp.broadcast_to(o, blk.shape[1:]) for o in outs])
 
         fn = jax.jit(
-            spmd(local, mesh=mesh, in_specs=P(None, axis), out_specs=P(None, axis))
+            jax.shard_map(
+                local, mesh=mesh, in_specs=P(None, axis), out_specs=P(None, axis)
+            )
         )
         _SPMD_RUNNERS[key] = fn
     return fn
@@ -128,7 +136,14 @@ class ShardedTileStore:
 
     def __init__(self, shards: tuple, tile_bounds: tuple, *, n_words: int,
                  r: int, mesh=None, axis: str = "data"):
-        self.shards: tuple = tuple(shards)
+        shards = tuple(shards)
+        if mesh is not None and len(shards) == _axis_size(mesh, axis):
+            # shard i lives on the i-th device along the axis
+            shards = tuple(
+                s.on_device(d) if hasattr(s, "on_device") else s
+                for s, d in zip(shards, _block_devices(mesh, axis))
+            )
+        self.shards: tuple = shards
         self.tile_bounds = tuple(tile_bounds)
         self.n_words = int(n_words)
         self.r = int(r)
@@ -163,26 +178,48 @@ class ShardedTileStore:
         """Global dense uint32[N, n_words] view (an explicit gather; cached
         -- the store is immutable)."""
         if self._dense_cache is None:
-            self._dense_cache = jnp.concatenate(
-                [s.densify() for s in self.shards], axis=1
-            )
+            self._dense_cache = _gather([s.densify() for s in self.shards], 1)
         return self._dense_cache
 
+    @property
+    def spmd_width(self) -> int:
+        """Words per device block of :meth:`spmd_dense`: the widest shard."""
+        return max(s.n_words for s in self.shards)
+
     def spmd_dense(self, mesh, axis: str) -> jax.Array:
-        """Padded, device-sharded dense view for the shard_map path
-        (cached per mesh/axis; columns stay resident across queries)."""
+        """Device-sharded dense view for the shard_map path: block ``i``
+        of the word axis is shard ``i``'s dense view, zero-padded to
+        :attr:`spmd_width` and assembled on its own device -- nothing is
+        gathered.  Needs one shard per device along ``axis``; cached per
+        mesh/axis, so columns stay resident across queries."""
         key = (mesh, axis)
         got = self._spmd_cache.get(key)
         if got is None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            s = _axis_size(mesh, axis)
-            dense = self.densify()
-            nw = dense.shape[1]
-            w = -(-nw // s)  # equal per-device width
-            if s * w != nw:
-                dense = jnp.pad(dense, ((0, 0), (0, s * w - nw)))
-            got = jax.device_put(dense, NamedSharding(mesh, P(None, axis)))
+            if _axis_size(mesh, axis) != self.n_shards:
+                raise ValueError(
+                    f"{self.n_shards} shards on a {axis!r} axis of size "
+                    f"{_axis_size(mesh, axis)}"
+                )
+            w = self.spmd_width
+            blocks = []
+            for s in self.shards:
+                d = s.densify()
+                if d.shape[1] != w:
+                    d = jnp.pad(d, ((0, 0), (0, w - d.shape[1])))
+                blocks.append(d)
+            sharding = NamedSharding(mesh, P(None, axis))
+            shape = (self.n, w * self.n_shards)
+            got = jax.make_array_from_single_device_arrays(
+                shape, sharding,
+                [
+                    jax.device_put(blocks[idx[1].start // w], dev)
+                    for dev, idx in sharding.addressable_devices_indices_map(
+                        shape
+                    ).items()
+                ],
+            )
             self._spmd_cache[key] = got
         return got
 
@@ -269,7 +306,7 @@ class ShardedResult:
 
     def gather(self) -> jax.Array:
         """Materialise the global packed bitmap (the one explicit gather)."""
-        return jnp.concatenate([jnp.asarray(s) for s in self.shards])
+        return _gather(self.shards)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -364,7 +401,7 @@ class ShardedBitmapIndex:
         if name not in self._slot:
             raise KeyError(f"unknown column {name!r}")
         i = self._slot[name]
-        return jnp.concatenate([s.densify()[i] for s in self.store.shards])
+        return _gather([s.densify()[i] for s in self.store.shards])
 
     # -- immutable updates -------------------------------------------------
     def add_column(self, name: str, result) -> "ShardedBitmapIndex":
@@ -514,13 +551,12 @@ class ShardedBitmapIndex:
             plans = ShardedPlan(tuple(shard_plans))
         self._last_plans = plans
         k = len(qlist)
-        spmd = _shard_map()
         if (
             self.mesh is not None
-            and spmd is not None
+            and _axis_size(self.mesh, self.store.axis) == self.n_shards
             and all(b in _SPMD_BACKENDS for b in plans.backends)
         ):
-            stacked = self._run_spmd(circ_fn(), k, spmd)
+            stacked = self._run_spmd(circ_fn(), k)
             self.last_info = {
                 "mode": "shard_map",
                 "backends": plans.backends,
@@ -540,21 +576,27 @@ class ShardedBitmapIndex:
             )
         return results
 
-    def _run_spmd(self, circuit, k: int, spmd) -> list:
+    def _run_spmd(self, circuit, k: int) -> list:
         """One shard_map over the device-sharded word axis: every device
         evaluates the same compiled circuit on its local words (threshold /
         symmetric functions are pointwise per row position, so the split is
         exact).  Columns, the jitted runner, and the results all stay
-        device-resident across calls (both caches are keyed structurally)."""
+        device-resident across calls (both caches are keyed structurally);
+        shard ``i``'s result is read from the block on its own device."""
         mesh, axis = self.mesh, self.store.axis
         arr = self.store.spmd_dense(mesh, axis)
-        fn = _spmd_runner(circuit, mesh, axis, self.n, spmd)
-        out = fn(arr)[:, : self.n_words]
-        # re-slice the global result at the store's real shard boundaries
+        fn = _spmd_runner(circuit, mesh, axis, self.n)
+        out = fn(arr)
+        w = self.store.spmd_width
+        homes = _block_devices(mesh, axis)
+        blocks = {
+            piece.index[1].start // w: piece.data
+            for piece in out.addressable_shards
+            if piece.device == homes[piece.index[1].start // w]
+        }
         per_shard = []
-        off = list(self.store.word_offsets) + [self.n_words]
-        for i in range(self.n_shards):
-            piece = out[:, off[i] : off[i + 1]]
+        for i, shard in enumerate(self.store.shards):
+            piece = blocks[i][:, : shard.n_words]
             per_shard.append([self._mask_shard(piece[j], i) for j in range(k)])
         return per_shard
 

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels run with interpret=True (the Pallas
-interpreter executes the kernel body in Python); on TPU backends the same
-call lowers through Mosaic.  ``INTERPRET`` auto-detects.
+Off the TPU (the CPU test path) the kernels run with interpret=True (the
+Pallas interpreter executes the kernel body in Python); on a TPU backend the
+same call lowers through Mosaic.  ``INTERPRET`` auto-detects.
 
 .. deprecated:: these wrappers are thin shims over ``repro.query``; prefer
    ``BitmapIndex.execute``, which plans the backend itself from TileStore

@@ -11,7 +11,7 @@ uint32 lanes), and writes a single (block_words,) output tile.
 HBM traffic drops from ~(1 + 2*5)x input bytes to ~(1 + 1/N)x -- the
 arithmetic intensity of the circuit (~5 VPU ops / 4 B) stays memory-bound,
 so traffic is the roofline term and the fusion is worth ~an order of
-magnitude (see EXPERIMENTS.md Perf, kernel section).
+magnitude (``benchmarks/kernel_bench.py`` models the traffic).
 
 Tiling: the word axis is split into ``block_words`` chunks (grid dim 0);
 the full N axis rides along in VMEM because every level of the adder needs
@@ -32,9 +32,9 @@ from repro.core import circuits as _ckt
 
 LANE_WORDS = 1024  # words per (8,128) int32 vreg tile
 
-# On the CPU container the kernels run with interpret=True (the Pallas
-# interpreter executes the kernel body in Python); on TPU backends the same
-# call lowers through Mosaic.
+# Off the TPU (the CPU test path) the kernels run with interpret=True: the
+# Pallas interpreter executes the kernel body in Python.  On a TPU backend
+# the same call lowers through Mosaic.
 INTERPRET = jax.default_backend() != "tpu"
 
 
@@ -119,20 +119,19 @@ def circuit_structural_key(circuit: _ckt.Circuit) -> tuple:
     return (circuit.n_inputs, tuple(circuit.ops), tuple(circuit.outputs))
 
 
-def run_circuit_cached(
-    bitmaps: jax.Array,
+def circuit_runner(
     circuit: _ckt.Circuit,
     *,
     block_words: int | None = None,
     interpret: bool = False,
     pallas: bool = True,
-) -> jax.Array:
-    """Evaluate ``circuit`` via a jitted runner cached by circuit structure.
+):
+    """The jitted evaluator of ``circuit``, cached by circuit structure.
 
     ``pallas=True`` lowers through :func:`run_circuit_pallas` (fused VMEM
     evaluation); otherwise the gate DAG is evaluated as straight-line jnp
-    bitwise code under one jit.  Returns uint32[n_words] (single output) or
-    uint32[k, n_words].
+    bitwise code under one jit.  The runner maps uint32[N, n_words] to
+    uint32[n_words] (single output) or uint32[k, n_words].
     """
     key = (circuit_structural_key(circuit), block_words, interpret, pallas)
     fn = _CIRCUIT_RUNNERS.get(key)
@@ -150,7 +149,21 @@ def run_circuit_cached(
                 return outs[0] if len(outs) == 1 else jnp.stack(outs)
         fn = jax.jit(run)
         _CIRCUIT_RUNNERS[key] = fn
-    return fn(bitmaps)
+    return fn
+
+
+def run_circuit_cached(
+    bitmaps: jax.Array,
+    circuit: _ckt.Circuit,
+    *,
+    block_words: int | None = None,
+    interpret: bool = False,
+    pallas: bool = True,
+) -> jax.Array:
+    """Evaluate ``circuit`` via :func:`circuit_runner`'s cached runner."""
+    return circuit_runner(
+        circuit, block_words=block_words, interpret=interpret, pallas=pallas
+    )(bitmaps)
 
 
 @functools.partial(

@@ -40,6 +40,18 @@ dispatches:
     constant-fold values, so unrestricted queries never round-trip
     through a host ``out`` array.
 
+Compile time on the TPU shapes three choices below: the block stage's
+toggle masks are scattered straight into 2-D ``[rows, tw]`` buffers,
+the prefix-XOR carries word parities with a small matmul instead of a
+scan (see :func:`_prefix_xor_words`), and every row scatter (``.set``)
+is declared ``indices_are_sorted`` -- an unsorted row scatter compiles
+in time that grows with its index count.  The plan builder
+(``repro.storage.tiled``) orders cells, toggles and output rows on the
+host, once per cached plan, so the indices are non-decreasing by
+construction.  The bit scatter-adds, whose (row, word) indices repeat
+whenever two toggles share a word, carry no such hint: with it, a TPU v5e
+returned wrong words once a stage reached 65537 rows.
+
 Carry-free scatter invariants (JAX has no XOR-scatter, so every scatter
 below must be provably collision-free under ``.at[].add``):
 
@@ -147,19 +159,37 @@ def _bit(pos):
 
 
 def _prefix_xor_words(t):
-    """Toggle masks uint32[rows, tw + 1] -> filled words uint32[rows, tw].
+    """Toggle masks uint32[rows, tw] -> filled words uint32[rows, tw].
 
     Device port of the tail of ``rasterize_toggles``: prefix-XOR within
-    each word by doubling shifts, then carry word parities across the row
-    with an associative scan (column ``tw`` catches toggles at the span
-    boundary and is dropped)."""
+    each word by doubling shifts, then carry word parities across the row.
+    The carry is a count of earlier odd words, taken as a 0/1 matmul with a
+    strictly upper-triangular matrix (exact: counts stay below 2**8): an
+    associative scan here made the TPU compiler take about half a minute
+    per decode at 2**26 rows.  A toggle at the span boundary changes no
+    bit of the tile, so callers drop it (``mode="drop"`` on the scatter
+    that builds ``t``)."""
     for sh in (1, 2, 4, 8, 16):
         t = t ^ (t << _U32(sh))
-    par = t >> _U32(31)
-    cum = jax.lax.associative_scan(jnp.bitwise_xor, par, axis=1)
-    fill = jnp.concatenate([jnp.zeros_like(cum[:, :1]), cum[:, :-1]], axis=1)
-    t = t ^ (fill * _U32(0xFFFFFFFF))
-    return t[:, :-1]
+    tw = t.shape[1]
+    par = (t >> _U32(31)).astype(jnp.bfloat16)
+    earlier = jnp.triu(jnp.ones((tw, tw), jnp.bfloat16), 1)
+    odd = jnp.dot(par, earlier, preferred_element_type=jnp.float32)
+    return t ^ ((odd.astype(_U32) & _U32(1)) * _U32(0xFFFFFFFF))
+
+
+def gather_tiles(pack, idx, tw: int):
+    """Tiles ``idx`` (int32[n]) of a dense pack stored ``pack.shape[1] //
+    tw`` tiles to a row (``TileStore.device_packs``) -> uint32[n, tw]."""
+    per_row = pack.shape[1] // tw
+    if per_row == 1:
+        return pack[idx]
+    rows = pack[idx // per_row].reshape(-1, per_row, tw)
+    slot = (idx % per_row)[:, None]
+    out = rows[:, 0]
+    for s in range(1, per_row):
+        out = jnp.where(slot == s, rows[:, s], out)
+    return out
 
 
 def _expand_base(base, tw):
@@ -174,20 +204,25 @@ def block_runner(circuits: tuple, m_max: int, k_max: int, tw: int,
     """Compiled block stage for a tuple of residual circuits.
 
     Returns ``fn(base, gids, dense_pack1, cell_src, sparse_pack1, sp_take,
-    sp_cell, sp_rows, run_pack1, rn_take, rn_cell, rn_rows, dst)`` where
+    sp_cell, sp_rows, run_pack1, rn_take, rn_cell, rn_rows, dst, perm)``
+    where
 
     * ``base``: uint32[k, n_sel1] constant fill values (expanded in-kernel)
       or uint32[k, n_sel1, tw] (already-assembled buffer from a previous
       stage); returns the updated [k, n_sel1, tw] buffer;
     * ``gids``: int32[nb] residual-group id per block;
-    * ``dense_pack1``: uint32[D + 2, tw] dense pack + zeros/ones sentinels;
-    * ``cell_src``: int32[nb * m_max * B + 1] dense-pack row per block cell
+    * ``dense_pack1``: the dense pack + zeros/ones sentinel tiles, stored
+      as :meth:`TileStore.device_packs` lays it out (see
+      :func:`gather_tiles`);
+    * ``cell_src``: int32[nb * m_max * B + 1] dense-pack tile per block cell
       (compressed cells point at the zeros sentinel and are overwritten by
       the decode prologue; the trailing entry is the scatter dummy row);
     * ``sp_take``/``sp_cell``: sparse payload take-indices and decode-row
-      ids; ``sp_rows``: block-cell row per decode row (dummy -> sentinel);
+      ids (non-decreasing, a cell's positions ascending); ``sp_rows``:
+      block-cell row per decode row, ascending (dummy -> sentinel);
     * ``rn_take``/``rn_cell``/``rn_rows``: same for run intervals;
-    * ``dst``: int32[nb * k_max * B] flat output cell per block lane.
+    * ``dst``: int32[nb * k_max * B] flat output cell per block lane,
+      ascending; ``perm``: the block lane written to each ``dst`` entry.
     """
     from repro.kernels.threshold_ssum import circuit_structural_key
 
@@ -240,32 +275,31 @@ def block_runner(circuits: tuple, m_max: int, k_max: int, tw: int,
             interpret=interpret,
         )(gids, x)
 
-    def run(base, gids, dense_pack1, cell_src,
-            sparse_pack1, sp_take, sp_cell, sp_rows,
-            run_pack1, rn_take, rn_cell, rn_rows, dst):
+    def tiled_block(base, gids, dense_pack1, cell_src,
+                    sparse_pack1, sp_take, sp_cell, sp_rows,
+                    run_pack1, rn_take, rn_cell, rn_rows, dst, perm):
         base = _expand_base(base, tw)
         nb = gids.shape[0]
         B = (cell_src.shape[0] - 1) // (nb * m_max)
         btw = B * tw
         # decode prologue: every residual-input cell materialised into the
         # block buffer straight from the container packs
-        blocks = dense_pack1[cell_src]  # [nb*m_max*B + 1, tw]
+        blocks = gather_tiles(dense_pack1, cell_src, tw)  # [nb*m_max*B + 1, tw]
         ncs1 = sp_rows.shape[0]
         pos = sparse_pack1[sp_take].astype(jnp.int32)
-        sw = (
-            jnp.zeros((ncs1 * tw,), _U32)
-            .at[sp_cell * tw + pos // 32]
-            .add(_bit(pos))
-            .reshape(ncs1, tw)
+        sw = jnp.zeros((ncs1, tw), _U32).at[sp_cell, pos // 32].add(
+            _bit(pos)
         )
-        blocks = blocks.at[sp_rows].set(sw)
+        blocks = blocks.at[sp_rows].set(sw, indices_are_sorted=True)
         ncr1 = rn_rows.shape[0]
         iv = run_pack1[rn_take].astype(jnp.int32)
-        t = jnp.zeros((ncr1 * (tw + 1),), _U32)
-        t = t.at[rn_cell * (tw + 1) + iv[:, 0] // 32].add(_bit(iv[:, 0]))
-        t = t.at[rn_cell * (tw + 1) + iv[:, 1] // 32].add(_bit(iv[:, 1]))
-        rw = _prefix_xor_words(t.reshape(ncr1, tw + 1))
-        blocks = blocks.at[rn_rows].set(rw)
+        t = jnp.zeros((ncr1, tw), _U32)
+        for end in (0, 1):  # interval starts, then ends
+            t = t.at[rn_cell, iv[:, end] // 32].add(
+                _bit(iv[:, end]), mode="drop"
+            )
+        rw = _prefix_xor_words(t)
+        blocks = blocks.at[rn_rows].set(rw, indices_are_sorted=True)
         x = blocks[:-1].reshape(nb, m_max, btw)
         if use_pallas:
             ys = _pallas_eval(gids, x)
@@ -275,10 +309,12 @@ def block_runner(circuits: tuple, m_max: int, k_max: int, tw: int,
                 return carry, _eval_block(g, xb)
 
             _, ys = jax.lax.scan(body, None, (gids, x))
-        out = base.reshape(-1, tw).at[dst].set(ys.reshape(-1, tw))
+        out = base.reshape(-1, tw).at[dst].set(
+            ys.reshape(-1, tw)[perm], indices_are_sorted=True
+        )
         return out.reshape(base.shape)
 
-    jitted = jax.jit(run)
+    jitted = jax.jit(tiled_block)
 
     def fn(base, gids, dense_pack1, cell_src, *rest):
         if _OBS.enabled:
@@ -292,13 +328,20 @@ def block_runner(circuits: tuple, m_max: int, k_max: int, tw: int,
     return fn
 
 
+def event_key_shift(tw: int) -> int:
+    """Bits below the row in an event sort key: toggle positions run from 0
+    to ``tw * 32``.  The stride is a power of two, so the kernel splits
+    keys with a shift and a mask and never divides."""
+    return (tw * 32).bit_length()
+
+
 def event_runner(k_max: int, mm: int, tw: int):
     """Compiled event stage: ``mm = 2 ** m_max`` is the stacked-LUT stride.
 
-    ``fn(base, keys, mask, gid_row, lut, out_dst)``:
+    ``fn(base, keys, mask, gid_row, lut, out_dst, out_perm)``:
 
-    * ``keys``: int32[e_pad] toggle sort keys, ``row * (tw * 32 + 2) +
-      pos`` -- PRE-SORTED ascending at plan-build time (the merge order is
+    * ``keys``: int32[e_pad] toggle sort keys, ``row << event_key_shift(tw)
+      | pos`` -- PRE-SORTED ascending at plan-build time (the merge order is
       pure store data, so the host sorts once per cached plan instead of
       the device sorting per query); pad entries carry the dummy row's
       key, which exceeds every real key;
@@ -311,7 +354,8 @@ def event_runner(k_max: int, mm: int, tw: int):
       combination ``combo``; entry 0 of each table is the background
       (all-inputs-zero) value;
     * ``out_dst``: int32[k_max, n_rows1] flat output cell per (output
-      slot, event row), dummies -> the buffer's dummy tile.
+      slot, event row), each row ascending, dummies -> the buffer's dummy
+      tile; ``out_perm``: the event row written to each ``out_dst`` entry.
     """
     key = ("event", k_max, mm, tw)
     fn = _RUNNERS.get(key)
@@ -319,20 +363,20 @@ def event_runner(k_max: int, mm: int, tw: int):
         _RUNNERS.move_to_end(key)
         return fn
 
-    stride = tw * 32 + 2
+    shift = event_key_shift(tw)
 
-    def run(base, keys, mask, gid_row, lut, out_dst):
+    def tiled_event(base, keys, mask, gid_row, lut, out_dst, out_perm):
         base = _expand_base(base, tw)
         n_rows1 = gid_row.shape[0]
         e = keys.shape[0]
         xacc = jax.lax.associative_scan(jnp.bitwise_xor, mask)
-        rows_s = keys // stride
-        pos_s = keys % stride
+        rows_s = keys >> shift
+        pos_s = keys & ((1 << shift) - 1)
         iota = jnp.arange(e, dtype=jnp.int32)
         prev_key = jnp.concatenate(
             [jnp.full((1,), -1, keys.dtype), keys[:-1]]
         )
-        starts = rows_s != prev_key // stride
+        starts = rows_s != prev_key >> shift
         firsts = keys != prev_key
         lasts = jnp.concatenate(
             [keys[1:] != keys[:-1], jnp.ones((1,), bool)]
@@ -349,7 +393,6 @@ def event_runner(k_max: int, mm: int, tw: int):
         )
         g_ev = gid_row[rows_s]
         base_flat = base.reshape(-1, tw)
-        t_size = n_rows1 * (tw + 1) + 1
         for j in range(k_max):
             lb = (g_ev * k_max + j) * mm
             vals = lut[lb + combo]
@@ -359,18 +402,19 @@ def event_runner(k_max: int, mm: int, tw: int):
             # of a (row, pos) run may toggle, and only if the value changed
             # relative to before the run
             tog = lasts & (vals != pv[fidx])
-            tidx = jnp.where(
-                tog, rows_s * (tw + 1) + pos_s // 32, t_size - 1
-            )
             tval = jnp.where(tog, _bit(pos_s), _U32(0))
-            t = jnp.zeros((t_size,), _U32).at[tidx].add(tval)
-            words = _prefix_xor_words(t[:-1].reshape(n_rows1, tw + 1))
+            t = jnp.zeros((n_rows1, tw), _U32).at[rows_s, pos_s // 32].add(
+                tval, mode="drop"
+            )
+            words = _prefix_xor_words(t)
             bg = lut[(gid_row * k_max + j) * mm].astype(bool)
             words = jnp.where(bg[:, None], ~words, words)
-            base_flat = base_flat.at[out_dst[j]].set(words)
+            base_flat = base_flat.at[out_dst[j]].set(
+                words[out_perm[j]], indices_are_sorted=True
+            )
         return base_flat.reshape(base.shape)
 
-    jitted = jax.jit(run)
+    jitted = jax.jit(tiled_event)
 
     def fn(base, keys, *rest):
         if _OBS.enabled:

@@ -11,18 +11,11 @@ state (the dry-run must set XLA_FLAGS before any jax initialisation).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
 
-    def _make_mesh(shape, axes) -> Mesh:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-except ImportError:  # older jax: Auto is the only behaviour
-
-    def _make_mesh(shape, axes) -> Mesh:
-        return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

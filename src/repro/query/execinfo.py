@@ -34,6 +34,9 @@ _RATIO = "ratio"        # recomputed from merged fields (numerator, denominator)
 EXEC_INFO_SCHEMA: dict[str, tuple] = {
     "backend": (_LABEL, ""),
     "engine": (_LABEL, ""),
+    #: how the device evaluated the circuit: "pallas" (a Pallas kernel,
+    #: Mosaic-compiled on TPU, interpreted elsewhere) or "xla" (jnp code)
+    "kernel": (_LABEL, ""),
     "n_tiles": (_SUM, 0),
     "selected_tiles": (_SUM, 0),
     "n_outputs": (_MAX, 1),

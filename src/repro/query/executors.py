@@ -141,6 +141,9 @@ def _dense_exec_info(backend: str, engine: str, n_rows: int, out,
     return make_exec_info(
         backend,
         engine=engine,
+        kernel="pallas" if backend == "fused" else (
+            "xla" if engine == "dense" else ""
+        ),
         n_outputs=k,
         total_words=total,
         words_touched=total,

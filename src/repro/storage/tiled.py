@@ -246,6 +246,9 @@ def run_tiled_circuit(
         total_words=int(store.n * nw),
     )
 
+    # results land on the store's device (a sharded store places each shard)
+    put = getattr(store, "put", jax.numpy.asarray)
+
     def _finish_host(out):
         info["work_fraction"] = info["dirty_words_gathered"] / max(
             1, info["total_words"]
@@ -255,7 +258,7 @@ def run_tiled_circuit(
         if restricted:
             return out, info  # host [k, n_sel, tw], caller patches per tile
         result = out.reshape(k, -1)[:, :nw]
-        return jax.numpy.asarray(result[0] if k == 1 else result), info
+        return put(result[0] if k == 1 else result), info
 
     if not support:
         # constant circuit: no data touched at all
@@ -344,7 +347,7 @@ def run_tiled_circuit(
         )
     return _run_merge_pass(
         store, merged, base_vals, info, sel, restricted,
-        k, tw, n_sel, interpret, pallas, block_words, _finish_host,
+        k, tw, n_sel, interpret, pallas, block_words, _finish_host, put,
     )
 
 
@@ -367,7 +370,7 @@ def _execute_scan_plan(plan, info):
         if restricted:
             return out, info
         result = out.reshape(k, -1)[:, :nw]
-        return jnp.asarray(result[0] if k == 1 else result), info
+        return plan["put"](result[0] if k == 1 else result), info
     buf = jnp.asarray(plan["bv"])
     for fn, args in plan["stages"]:
         buf = fn(buf, *args)
@@ -381,8 +384,6 @@ def _execute_scan_plan(plan, info):
 
 def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
                    k, tw, nw, n_sel, interpret, pallas, cache, pkey):
-    import jax.numpy as jnp
-
     from repro.kernels import tiled_scan
 
     n_sel1 = n_sel + 1
@@ -394,7 +395,7 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
     s_index, s_bounds = packs["sparse_index"], packs["sparse_bounds"]
     r_index, r_bounds = packs["run_index"], packs["run_bounds"]
     dense_pack1, sparse_pack1, run_pack1 = store.device_packs()
-    D = int(dense_pack1.shape[0]) - 2  # zeros sentinel row; ones = D + 1
+    D = int(packs["dense_pack"].shape[0])  # zeros sentinel tile; ones = D + 1
     S = int(sparse_pack1.shape[0]) - 1  # zero pad entry
     R = int(run_pack1.shape[0]) - 1
     dummy_out = n_sel  # flat [k, n_sel1] dummy cell: tile n_sel of output 0
@@ -416,7 +417,7 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
         groups.append([res, live, tables, ents])
 
     # ---- split each group's tiles: device event merge vs block decode ----
-    stride = tw * 32 + 2
+    stride = 1 << tiled_scan.event_key_shift(tw)
     n_ev = 0
     for g in groups:
         res, live, tables, ents = g
@@ -444,7 +445,8 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
     bv = np.zeros((k, n_sel1), np.uint32)
     bv[:, :n_sel] = base_vals
     plan = {
-        "bv": bv, "base_vals": base_vals, "stages": [],
+        "bv": store.put(bv), "put": store.put, "base_vals": base_vals,
+        "stages": [],
         "k": k, "n_sel": n_sel, "tw": tw, "nw": nw, "restricted": restricted,
     }
 
@@ -524,6 +526,10 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
         for live, rows, ot in out_parts:
             for j, oj in enumerate(live):
                 out_dst[j, rows] = oj * n_sel1 + ot
+        # the kernel's scatters take ascending indices: write rows in
+        # output-cell order
+        out_perm = np.argsort(out_dst, axis=1, kind="stable").astype(np.int32)
+        out_dst = np.take_along_axis(out_dst, out_perm, axis=1)
 
         # toggle merge order is pure store data: sort once here (host,
         # cached with the plan) so the kernel never pays a device sort
@@ -541,9 +547,9 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
         )
         fn = tiled_scan.event_runner(k_max_ev, mm, tw)
         plan["stages"].append((fn, (
-            jnp.asarray(keys_s), jnp.asarray(mask_s),
-            jnp.asarray(gid_row), jnp.asarray(lut.reshape(-1)),
-            jnp.asarray(out_dst),
+            store.put(keys_s), store.put(mask_s),
+            store.put(gid_row), store.put(lut.reshape(-1)),
+            store.put(out_dst), store.put(out_perm),
         )))
         info["launches"] += 1
 
@@ -641,17 +647,27 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
         cell_src[: nb * m_max * B] = np.concatenate(src_p).reshape(-1)
         dst = np.full(nb_pad * k_max * B, dummy_out, np.int64)
         dst[: nb * k_max * B] = np.concatenate(dst_p).reshape(-1)
+        perm = np.argsort(dst, kind="stable")  # ascending scatter indices
 
         def _decode(take_p, cell_p, row_p, nc, pad_take):
             t = np.concatenate(take_p) if take_p else np.zeros(0, np.int64)
             c = np.concatenate(cell_p) if cell_p else np.zeros(0, np.int64)
             rr = np.concatenate(row_p) if row_p else np.zeros(0, np.int64)
+            if nc:
+                # renumber the decoded cells in block-row order, so the
+                # kernel's row scatters take ascending indices
+                order = np.argsort(rr, kind="stable")
+                cnt = np.bincount(c, minlength=nc)
+                start = np.concatenate([[0], np.cumsum(cnt)[:-1]])
+                t = t[concat_ranges(start[order], start[order] + cnt[order])]
+                c = np.repeat(np.arange(nc), cnt[order])
+                rr = rr[order]
             nc1 = pow2(max(1, nc)) + 1
             size = pow2(max(1, t.size))
             return (
-                jnp.asarray(padv(t.astype(np.int32), size, pad_take)),
-                jnp.asarray(padv(c.astype(np.int32), size, nc1 - 1)),
-                jnp.asarray(padv(rr.astype(np.int32), nc1, NBC)),
+                store.put(padv(t.astype(np.int32), size, pad_take)),
+                store.put(padv(c.astype(np.int32), size, nc1 - 1)),
+                store.put(padv(rr.astype(np.int32), nc1, NBC)),
             )
 
         spt, spc, spr = _decode(spt_p, spc_p, spr_p, ncs, S)
@@ -662,12 +678,14 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
         fn = tiled_scan.block_runner(
             circuits, m_max, k_max, tw, use_pallas, interpret
         )
+        info["kernel"] = "pallas" if use_pallas else "xla"
         plan["stages"].append((fn, (
-            jnp.asarray(gids), dense_pack1,
-            jnp.asarray(cell_src.astype(np.int32)),
+            store.put(gids), dense_pack1,
+            store.put(cell_src.astype(np.int32)),
             sparse_pack1, spt, spc, spr,
             run_pack1, rnt, rnc, rnr,
-            jnp.asarray(dst.astype(np.int32)),
+            store.put(dst[perm].astype(np.int32)),
+            store.put(perm.astype(np.int32)),
         )))
         info["launches"] += 1
 
@@ -688,7 +706,7 @@ def _run_scan_pass(store, merged, base_vals, info, sel, restricted,
 
 def _run_merge_pass(store, merged, base_vals, info, sel, restricted,
                     k, tw, n_sel, interpret, pallas, block_words,
-                    _finish_host):
+                    _finish_host, put):
     import jax
 
     from repro.kernels.threshold_ssum import run_circuit_cached
@@ -833,12 +851,13 @@ def _run_merge_pass(store, merged, base_vals, info, sel, restricted,
                 rows = np.concatenate(dense_gathers, axis=1)  # [m, nd]
                 gathered = store.dirty[rows.reshape(-1)].reshape(m, -1)
             else:
-                gathered = jax.numpy.asarray(
+                gathered = put(
                     np.concatenate(dense_gathers, axis=1)
                 )
             info["dirty_words_gathered"] += int(gathered.size)
             info["densified_tiles"] += int(tiles.size)
             info["launches"] += 1
+            info["kernel"] = "pallas" if pallas else "xla"
             got = run_circuit_cached(
                 gathered, res,
                 block_words=block_words, interpret=interpret, pallas=pallas,

@@ -38,6 +38,7 @@ working (the property ``BitmapIndex.add_column`` relies on).
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 
 import jax
@@ -314,8 +315,11 @@ class TileStore:
     """Tile-classified columns: classes + per-kind packed container arrays."""
 
     def __init__(self, columns: list, *, tile_words: int, n_words: int, r: int,
-                 dense=None, containers: bool = True):
+                 dense=None, containers: bool = True, device=None):
         self._cols: tuple = tuple(columns)
+        #: the device holding this store's device arrays (dense view,
+        #: container packs); None: JAX's default device
+        self.device = device
         self.tile_words = int(tile_words)
         self.n_words = int(n_words)
         self.r = int(r)
@@ -446,31 +450,35 @@ class TileStore:
         """Device-resident pack mirrors for the single-scan engine
         (``repro.kernels.tiled_scan``), uploaded once per store and cached:
 
-        * ``dense_pack1`` uint32[D + 2, tile_words] -- the dense pack plus
-          an all-zeros sentinel row at ``D`` and an all-ones row at
-          ``D + 1``, so clean cells gather by class without a branch;
+        * ``dense_pack1`` -- the dense pack plus an all-zeros sentinel tile
+          at ``D`` and an all-ones tile at ``D + 1``, so clean cells
+          gather by class without a branch.  64-word tiles are stored two
+          to a row, as uint32[rows, 128]: a TPU lays uint32[D, 64] out
+          transposed, and every row gather then copied the whole pack
+          into a lane-padded buffer twice its size first (narrower tiles
+          gather from the transposed layout without that copy).  Gather
+          tiles with :func:`repro.kernels.tiled_scan.gather_tiles`;
         * ``sparse_pack1`` uint16[S + 1] -- one zero pad entry so padded
           gathers read a harmless position;
         * ``run_pack1`` uint16[R + 1, 2] -- one (0, 0) pad interval (an
           empty run toggles twice at bit 0: a no-op under prefix-xor).
         """
         if self._device_packs is None:
-            import jax.numpy as jnp
-
             self._assemble_packs()
             p = self._packs
             tw = self.tile_words
-            dense1 = np.concatenate([
-                p["dense_pack"],
-                np.zeros((1, tw), np.uint32),
-                np.full((1, tw), 0xFFFFFFFF, np.uint32),
-            ])
+            per_row = 2 if tw == 64 else 1
+            d = p["dense_pack"].shape[0]
+            dense1 = np.zeros((-(-(d + 2) // per_row) * per_row, tw), np.uint32)
+            dense1[:d] = p["dense_pack"]
+            dense1[d + 1] = 0xFFFFFFFF
+            dense1 = dense1.reshape(-1, per_row * tw)
             sparse1 = np.concatenate([p["sparse_pack"],
                                       np.zeros(1, np.uint16)])
             run1 = np.concatenate([p["run_pack"],
                                    np.zeros((1, 2), np.uint16)])
             self._device_packs = (
-                jnp.asarray(dense1), jnp.asarray(sparse1), jnp.asarray(run1)
+                self.put(dense1), self.put(sparse1), self.put(run1)
             )
         return self._device_packs
 
@@ -677,6 +685,7 @@ class TileStore:
             raise ValueError("pack sizes inconsistent with the kind arrays")
         store = object.__new__(cls)
         store._cols = tuple(cols)
+        store.device = None
         store.tile_words = int(tile_words)
         store.n_words = int(n_words)
         store.r = int(r)
@@ -728,11 +737,12 @@ class TileStore:
         dense = None
         if self._dense is not None:
             dense = jnp.concatenate(
-                [self._dense, jnp.asarray(packed_row, WORD_DTYPE)[None]], axis=0
+                [self._dense, self.put(jnp.asarray(packed_row, WORD_DTYPE))[None]],
+                axis=0,
             )
         return TileStore(list(self._cols) + [col], tile_words=self.tile_words,
                          n_words=self.n_words, r=self.r, dense=dense,
-                         containers=self.containers)
+                         containers=self.containers, device=self.device)
 
     def replace(self, i: int, packed_row) -> "TileStore":
         """New store with column ``i`` swapped; only its tiles are reclassified
@@ -742,9 +752,12 @@ class TileStore:
         cols[int(i)] = col
         dense = None
         if self._dense is not None:
-            dense = self._dense.at[int(i)].set(jnp.asarray(packed_row, WORD_DTYPE))
+            dense = self._dense.at[int(i)].set(
+                self.put(jnp.asarray(packed_row, WORD_DTYPE))
+            )
         return TileStore(cols, tile_words=self.tile_words, n_words=self.n_words,
-                         r=self.r, dense=dense, containers=self.containers)
+                         r=self.r, dense=dense, containers=self.containers,
+                         device=self.device)
 
     def apply_tile_updates(self, updates: dict, *, r: int | None = None
                            ) -> "TileStore":
@@ -794,7 +807,7 @@ class TileStore:
             cols.append(self._respliced_column(old, upd, n_tiles_new, growth))
         # dense view: dropped, rebuilt lazily from tiles on first densify()
         return TileStore(cols, tile_words=tw, n_words=nw_new, r=r_new,
-                         containers=self.containers)
+                         containers=self.containers, device=self.device)
 
     def _respliced_column(self, old: _Column, upd: dict, n_tiles_new: int,
                           growth: int) -> _Column:
@@ -904,8 +917,10 @@ class TileStore:
         """Reclassify the whole store at a different tile granularity."""
         if tile_words == self.tile_words:
             return self
-        return TileStore.from_packed(self.densify(), tile_words=tile_words,
-                                     r=self.r, containers=self.containers)
+        return TileStore.from_packed(
+            self.densify(), tile_words=tile_words, r=self.r,
+            containers=self.containers,
+        ).on_device(self.device)
 
     def slice_tiles(self, t0: int, t1: int) -> "TileStore":
         """New store over the tile range [t0, t1) -- the row-space shard
@@ -927,7 +942,8 @@ class TileStore:
         if self._dense is not None:
             dense = self._dense[:, w0 : w0 + nw_local]
         return TileStore(cols, tile_words=tw, n_words=nw_local, r=r_local,
-                         dense=dense, containers=self.containers)
+                         dense=dense, containers=self.containers,
+                         device=self.device)
 
     @classmethod
     def concat_tiles(cls, stores, *, n_words: int | None = None,
@@ -950,7 +966,11 @@ class TileStore:
         ]
         dense = None
         if all(s._dense is not None for s in stores):
-            dense = jnp.concatenate([s._dense for s in stores], axis=1)
+            # shards may sit on different devices: gather onto the default
+            home = jax.devices()[0]
+            dense = jnp.concatenate(
+                [jax.device_put(s._dense, home) for s in stores], axis=1
+            )
         return cls(cols, tile_words=tw, n_words=n_words, r=r, dense=dense,
                    containers=first.containers)
 
@@ -964,7 +984,7 @@ class TileStore:
         """The densified dirty-tile words, uint32[total_dirty, tile_words]
         (compressed containers expanded on first access)."""
         if self._dirty_dev is None:
-            self._dirty_dev = jnp.asarray(self._dirty_np)
+            self._dirty_dev = self.put(self._dirty_np)
         return self._dirty_dev
 
     @property
@@ -1043,8 +1063,29 @@ class TileStore:
     def densify(self) -> jax.Array:
         """Dense uint32[N, n_words] view (cached) for dense-path backends."""
         if self._dense is None:
-            self._dense = jnp.asarray(self._padded_host()[:, : self.n_words])
+            self._dense = self.put(self._padded_host()[:, : self.n_words])
         return self._dense
+
+    def put(self, x) -> jax.Array:
+        """``x`` as a device array on this store's device."""
+        if self.device is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self.device)
+
+    def on_device(self, device) -> "TileStore":
+        """This store with its device arrays (dense view, container packs)
+        on ``device``.  Host-side tiles, packs and statistics are shared;
+        device arrays not yet built are built there on first use."""
+        if device is None or device == self.device:
+            return self
+        store = copy.copy(self)
+        store.device = device
+        if self._dense is not None:
+            store._dense = jax.device_put(self._dense, device)
+        store._device_packs = None
+        store._dirty_dev = None
+        store.__dict__.pop("_scan_plan_cache", None)  # holds device arrays
+        return store
 
     def column(self, i: int) -> jax.Array:
         return self.densify()[int(i)]

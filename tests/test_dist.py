@@ -7,13 +7,6 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
-
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="substrate tests use the jax>=0.5 top-level shard_map API",
-)
 
 ENV = {**os.environ, "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
        "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..", "src")}

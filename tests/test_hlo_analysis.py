@@ -53,8 +53,6 @@ def test_cost_analysis_undercounts_vs_loop_aware():
 
     compiled = jax.jit(f).lower(jnp.ones((48, 48))).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # jax <= 0.4.x returns one dict per program
-        ca = ca[0] if ca else {}
     xla_flops = ca.get("flops", 0.0)
     la = analyze_hlo(compiled.as_text())
     assert la["dot_flops"] == 16 * 2 * 48**3
@@ -79,12 +77,8 @@ def f(x):
     out, _ = jax.lax.scan(body, x, None, length=5)
     return out
 
-try:  # jax >= 0.5
-    _shard_map, _kw = jax.shard_map, {"check_vma": False}
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _kw = {"check_rep": False}
-g = jax.jit(_shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None), **_kw))
+g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None),
+                          check_vma=False))
 hlo = g.lower(jnp.ones((1024,))).compile().as_text()
 r = analyze_hlo(hlo)
 assert r["collective_counts"]["all-reduce"] == 5, r["collective_counts"]

@@ -445,6 +445,31 @@ def test_calibration_persist_roundtrip(tmp_path, _no_calibration):
     assert get_calibration() is got
 
 
+def test_measure_calibration_surfaces_backend_errors(monkeypatch, _no_calibration):
+    """A backend that fails while it is measured (a kernel the compiler
+    refuses, say) raises out of the measurement pass instead of silently
+    vanishing from the constants.  Only a backend the planner never offers
+    on this device -- ``fused`` off the TPU -- is skipped."""
+    from repro.core.calibration import measure_calibration
+    from repro.query import BitmapIndex
+    from repro.query.index import _fused_available
+
+    calib = measure_calibration(("fused", "ssum"), n=8, n_words=64, repeats=1)
+    assert "ssum" in calib.us_per_kword
+    assert ("fused" in calib.us_per_kword) == _fused_available()
+
+    real = BitmapIndex.execute
+
+    def failing(self, query, *, backend=None, block_words=None):
+        if backend == "ssum":
+            raise RuntimeError("ssum refused by the compiler")
+        return real(self, query, backend=backend, block_words=block_words)
+
+    monkeypatch.setattr(BitmapIndex, "execute", failing)
+    with pytest.raises(RuntimeError, match="refused by the compiler"):
+        measure_calibration(("wide_or", "ssum"), n=8, n_words=64, repeats=1)
+
+
 def test_plan_memo_invalidated_by_calibration_swap(_no_calibration):
     """Swapping calibration constants must not serve stale memoized plans:
     the memo key embeds the calibration generation."""

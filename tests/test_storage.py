@@ -504,6 +504,56 @@ def test_scan_engine_pallas_grid_parity():
     np.testing.assert_array_equal(np.asarray(out_xla), np.asarray(out_pl))
 
 
+def test_scan_plan_scatter_indices_ascend():
+    """The scan kernels declare every row scatter ``indices_are_sorted``
+    (the TPU compiler needs it; the CPU ignores it), so the plan builder
+    must hand them non-decreasing indices: decoded cells in block-row
+    order, output cells ascending with the permutation that feeds them.
+    The event stage's merge also needs its toggle keys sorted."""
+    rng = np.random.default_rng(23)
+    mixed, _r = _mixed_bits(seed=37)
+    sparse_bits = rng.random((6, 16 * SPAN)) < 20 / SPAN
+    # column 0 clean on odd tiles: two residual groups, interleaved tiles
+    sparse_bits[0].reshape(16, SPAN)[1::2] = False
+    # one residual group spanning several 16-tile blocks, with sparse and
+    # run cells on different wires: cell order != block-row order
+    wide = np.zeros((6, 40 * SPAN), bool)
+    wide[0] = rng.random(40 * SPAN) < 0.5
+    wide[1, ::97] = True
+    wide[2, 5::89] = True
+    wide[3] = (np.arange(40 * SPAN) % SPAN) // 700 == 1
+    wide[4] = (np.arange(40 * SPAN) % SPAN) // 500 == 2
+    kinds = set()
+
+    def ascending(x):
+        assert np.all(np.diff(np.asarray(x, np.int64)) >= 0)
+
+    for bits, tw in ((mixed, 8), (sparse_bits, TW), (wide, TW)):
+        store = _store_of(bits, containers=True, tile_words=tw)
+        run_tiled_circuit(store, build_interval_circuit(6, 2, 4), engine="scan")
+        (plan, _info), = store._scan_plan_cache.values()
+        for _fn, args in plan["stages"]:
+            args = [np.asarray(a) for a in args]
+            if len(args) == 13:  # block stage
+                kinds.add("block")
+                (_gids, _dense, _src, _sparse, _spt, _spc, spr,
+                 _runs, _rnt, _rnc, rnr, dst, perm) = args
+                ascending(spr)
+                ascending(rnr)
+                ascending(dst)
+                np.testing.assert_array_equal(np.sort(perm),
+                                              np.arange(perm.size))
+            else:  # event stage
+                kinds.add("event")
+                keys, _mask, _gid_row, _lut, out_dst, out_perm = args
+                ascending(keys)
+                for row, p in zip(out_dst, out_perm):
+                    ascending(row)
+                    np.testing.assert_array_equal(np.sort(p),
+                                                  np.arange(p.size))
+    assert kinds == {"block", "event"}
+
+
 def test_specialize_memo_is_lru():
     """The residual memo evicts oldest-used entries one at a time (not a
     wholesale clear), and a hit refreshes recency."""
