@@ -482,24 +482,20 @@ class ShardedBitmapIndex:
         return lambda: circuit_for(qs, self.n, self._names)
 
     def _execute_circuit(self, qs: tuple, qlist, backend, block_words) -> list:
-        import time as _time
-
         import repro.obs as _obs
         from repro.obs import trace as _trace
 
-        active = _trace.enabled or _obs.REGISTRY.enabled
-        t0 = _time.perf_counter() if active else 0.0
         with _trace.span(
             "execute_sharded", n_shards=self.n_shards, n_queries=len(qlist)
         ) as root:
             out = self._execute_circuit_inner(
                 qs, qlist, backend, block_words
             )
-            if active:
-                self._observe(root, _time.perf_counter() - t0)
+            if _trace.enabled or _obs.REGISTRY.enabled:
+                self._observe(root)
         return out
 
-    def _observe(self, root, wall_s: float) -> None:
+    def _observe(self, root) -> None:
         """Predicted-vs-measured accounting for the whole sharded call."""
         import repro.obs as _obs
 
@@ -519,9 +515,7 @@ class ShardedBitmapIndex:
             measured_words=measured,
         )
         if measured is not None:
-            _obs.record_drift(
-                label, sum(costs) if costs else None, measured, wall_s
-            )
+            _obs.record_drift(label, sum(costs) if costs else None, measured)
 
     def _execute_circuit_inner(self, qs: tuple, qlist, backend, block_words) -> list:
         circ_fn = self._circuit_fn(qs)

@@ -13,6 +13,10 @@ accounting made operational):
 * ``record_drift()`` -- the predicted-vs-realised words ratio as a
   first-class metric feeding the calibration feedback story.
 * ``SLOW_QUERIES`` -- threshold-gated ring buffer of slow span trees.
+* profiler clock -- while enabled, every span and every garbage
+  collection is also a ``repro:<name>`` annotation on JAX's profiler
+  trace (``repro:gc`` carries the generation), so device idle time can
+  be matched to the host work that left the device idle.
 * ``dump()`` / ``export_prometheus()`` / ``export_jsonl()`` -- snapshot
   surfaces (also ``python benchmarks/run.py obs``).
 
@@ -27,6 +31,7 @@ Typical production setup::
 """
 from __future__ import annotations
 
+import gc
 import json
 
 from repro.obs import trace as trace
@@ -60,14 +65,11 @@ DRIFT_RATIO = REGISTRY.histogram(
     "repro_calibration_drift_ratio",
     "measured_words / predicted_words per query", ("backend",),
 )
-QUERY_WALL = REGISTRY.histogram(
-    "repro_query_wall_seconds", "End-to-end query wall time", ("backend",),
-)
 QUERY_WORDS = REGISTRY.histogram(
     "repro_query_words_touched", "Measured words touched per query", ("backend",),
 )
 
-#: per-backend (wall, words, ratio) HistogramStates, cached so the hot
+#: per-backend (words, ratio) HistogramStates, cached so the hot
 #: :func:`record_drift` takes the registry lock once per query instead of
 #: once per family (cleared by :func:`reset` alongside the series).
 _DRIFT_STATES: dict = {}
@@ -80,11 +82,27 @@ def _on_root(root: Span) -> None:
 
 trace.add_root_listener(_on_root)
 
+_GC_NOTE: list = [None]  # the open ``repro:gc`` annotation (collections never overlap)
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: bracket each collection with a ``repro:gc``
+    profiler annotation on the collecting thread."""
+    if phase == "start":
+        note = trace.annotation("gc", generation=info["generation"])
+        note.__enter__()
+        _GC_NOTE[0] = note
+    elif _GC_NOTE[0] is not None:
+        _GC_NOTE[0].__exit__(None, None, None)
+        _GC_NOTE[0] = None
+
 
 def enable(slow_query_threshold_s: float | None = None) -> None:
     """Turn on metrics + tracing (and optionally set the slow-query bar)."""
     REGISTRY.enabled = True
     trace.enabled = True
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
     if slow_query_threshold_s is not None:
         SLOW_QUERIES.set_threshold(slow_query_threshold_s)
 
@@ -92,6 +110,8 @@ def enable(slow_query_threshold_s: float | None = None) -> None:
 def disable() -> None:
     REGISTRY.enabled = False
     trace.enabled = False
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
 
 
 def enabled() -> bool:
@@ -119,7 +139,7 @@ def histogram(name: str, help: str = "", labels=()) -> Histogram:
 
 
 def record_drift(backend: str, predicted_words: float | None,
-                 measured_words: float, wall_s: float) -> None:
+                 measured_words: float) -> None:
     """One predicted-vs-realised observation (no-op when disabled)."""
     if not REGISTRY.enabled:
         return
@@ -130,11 +150,10 @@ def record_drift(backend: str, predicted_words: float | None,
         with lock:
             states = _DRIFT_STATES[backend] = tuple(
                 fam._series.setdefault(key, HistogramState())
-                for fam in (QUERY_WALL, QUERY_WORDS, DRIFT_RATIO)
+                for fam in (QUERY_WORDS, DRIFT_RATIO)
             )
-    wall_st, words_st, ratio_st = states
+    words_st, ratio_st = states
     with lock:
-        wall_st.observe(wall_s)
         words_st.observe(measured_words)
         if predicted_words and predicted_words > 0:
             ratio_st.observe(measured_words / predicted_words)

@@ -14,6 +14,14 @@ running ``span("execute")`` nests automatically, including across the
 serving front-end's batcher thread (each thread/context gets its own
 stack).  When tracing is disabled, ``span()`` returns a shared no-op
 singleton: one branch, zero allocation.
+
+While tracing is enabled every entered span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro:<span name>`` on the
+thread that runs it, carrying the attributes the span opened with, so a
+profiler trace shows each span on the device trace's clock, on that
+thread's host line.  :func:`annotation` opens such an annotation alone,
+for host phases that belong to no request (the batcher's coalescing
+sleep, the garbage collector).
 """
 from __future__ import annotations
 
@@ -24,10 +32,25 @@ enabled = False  # toggled by repro.obs.enable()/disable()
 
 _CURRENT: ContextVar["Span | None"] = ContextVar("repro_obs_span", default=None)
 _ROOT_LISTENERS: list = []
+#: profiler annotation prefix: every span shows in a trace as ``repro:<name>``
+PREFIX = "repro:"
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def annotation(name: str, **attrs):
+    """A profiler annotation ``repro:<name>`` carrying ``attrs``, to be
+    entered and exited on one thread (JAX's profiler is imported on the
+    first call); the no-op :data:`NULL_SPAN` when tracing is disabled."""
+    global _TraceAnnotation
+    if not enabled:
+        return NULL_SPAN
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(PREFIX + name, **attrs)
 
 
 class Span:
-    __slots__ = ("name", "attrs", "children", "t0", "wall_s", "_token")
+    __slots__ = ("name", "attrs", "children", "t0", "wall_s", "_token", "_note")
 
     def __init__(self, name: str, attrs: dict | None = None) -> None:
         self.name = name
@@ -36,6 +59,7 @@ class Span:
         self.t0 = 0.0
         self.wall_s = 0.0
         self._token = None
+        self._note = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -46,11 +70,15 @@ class Span:
         if parent is not None:
             parent.children.append(self)
         self._token = _CURRENT.set(self)
+        self._note = annotation(self.name, **self.attrs)
+        self._note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.wall_s = time.perf_counter() - self.t0
+        self._note.__exit__(None, None, None)
+        self._note = None
         _CURRENT.reset(self._token)
         if _CURRENT.get() is None:
             for fn in _ROOT_LISTENERS:

@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time as _time
 import weakref
 from collections import OrderedDict
 
@@ -47,7 +46,7 @@ from repro.storage import TileStore, run_tiled_circuit
 
 from .compile import build_query_circuit
 from .expr import Col, Query, Threshold, as_query, canonical_key
-from .executors import ShardContext, run_plan
+from .executors import ShardContext, _dense_exec_info, run_plan
 
 __all__ = [
     "BitmapIndex",
@@ -532,17 +531,14 @@ class BitmapIndex:
         words next to the executor's measured words, and records one
         calibration-drift observation."""
         q = as_query(query)
-        active = _trace.enabled or _obs.REGISTRY.enabled
-        t0 = _time.perf_counter() if active else 0.0
         with _trace.span("execute") as root:
             plan = Plan(backend, "caller override") if backend else self.explain(q)
             out = self._mask(self._run(q, plan.algorithm, block_words))
-            if active:
-                self._observe(root, plan, self.last_info,
-                              _time.perf_counter() - t0)
+            if _trace.enabled or _obs.REGISTRY.enabled:
+                self._observe(root, plan, self.last_info)
         return out
 
-    def _observe(self, root, plan, info, wall_s: float) -> None:
+    def _observe(self, root, plan, info) -> None:
         """Annotate the root span with predicted vs measured words and feed
         the drift metric (called with obs tracing or metrics enabled)."""
         measured = (
@@ -557,7 +553,7 @@ class BitmapIndex:
             )
         _obs.record_drift(
             str(plan.algorithm), plan.cost,
-            measured if measured is not None else 0, wall_s,
+            measured if measured is not None else 0,
         )
 
     def execute_many(self, queries, *, backend: str | None = None,
@@ -567,7 +563,6 @@ class BitmapIndex:
         shares ONE dirty-tile gather; on the dense path, one jitted call."""
         qs = [as_query(q) for q in queries]
         active = _trace.enabled or _obs.REGISTRY.enabled
-        t0 = _time.perf_counter() if active else 0.0
         with _trace.span("execute_many", n_queries=len(qs)) as root:
             plans = [
                 Plan(backend, "caller override") if backend else self.explain(q)
@@ -588,38 +583,33 @@ class BitmapIndex:
                 tiled = backend == "tiled_fused" or (
                     backend is None and all(algs[i] == "tiled_fused" for i in batch)
                 )
-                if tiled:
-                    tdisp = _time.perf_counter() if active else 0.0
-                    with _trace.span(
-                        "dispatch", backend="tiled_fused", batched=len(batch)
-                    ) as sp:
-                        circ = self._circuit_for(tuple(qs[i] for i in batch))
+                bqs = tuple(qs[i] for i in batch)
+                bbackend = "tiled_fused" if tiled else (
+                    backend or ("fused" if _fused_available() else "circuit")
+                )
+                with _trace.span(
+                    "dispatch", backend=bbackend, batched=len(batch)
+                ) as sp:
+                    if tiled:
                         stacked, info = run_tiled_circuit(
-                            self.store, circ, block_words=block_words
+                            self.store, self._circuit_for(bqs),
+                            block_words=block_words,
                         )
-                        if _trace.enabled:
-                            _annotate_dispatch(sp, info)
-                    self.last_info = info
-                    if active:
-                        # one drift sample for the shared gather: the batch's
-                        # summed prediction vs the one realised gather
-                        bc = [plans[i].cost for i in batch]
-                        pred = (
-                            sum(c for c in bc if c is not None)
-                            if any(c is not None for c in bc) else None
-                        )
-                        _obs.record_drift(
-                            "tiled_fused", pred, info["words_touched"],
-                            _time.perf_counter() - tdisp,
-                        )
-                else:
-                    cbackend = backend or ("fused" if _fused_available() else "circuit")
-                    with _trace.span(
-                        "dispatch", backend=cbackend, batched=len(batch)
-                    ):
-                        stacked = self._dense_eval(
-                            tuple(qs[i] for i in batch), cbackend, block_words
-                        )
+                    else:
+                        stacked = self._dense_eval(bqs, bbackend, block_words)
+                        info = _dense_exec_info(bbackend, "dense", self.n, stacked)
+                    if _trace.enabled:
+                        _annotate_dispatch(sp, info)
+                self.last_info = info
+                if active and tiled:
+                    # one drift sample for the shared gather: the batch's
+                    # summed prediction vs the one realised gather
+                    bc = [plans[i].cost for i in batch]
+                    pred = (
+                        sum(c for c in bc if c is not None)
+                        if any(c is not None for c in bc) else None
+                    )
+                    _obs.record_drift("tiled_fused", pred, info["words_touched"])
                 if stacked.ndim == 1:
                     stacked = stacked[None]
                 for j, i in enumerate(batch):
@@ -628,7 +618,6 @@ class BitmapIndex:
                 batch = []
             for i, (q, alg) in enumerate(zip(qs, algs)):
                 if i not in results:
-                    tq = _time.perf_counter() if active else 0.0
                     results[i] = self._run(q, alg, block_words)
                     if active:
                         inf = self.last_info
@@ -636,10 +625,7 @@ class BitmapIndex:
                             inf.get("words_touched")
                             if isinstance(inf, dict) else None
                         )
-                        _obs.record_drift(
-                            str(alg), plans[i].cost, m or 0,
-                            _time.perf_counter() - tq,
-                        )
+                        _obs.record_drift(str(alg), plans[i].cost, m or 0)
             if _trace.enabled:
                 costs = [p.cost for p in plans if p.cost is not None]
                 info = self.last_info

@@ -43,6 +43,7 @@ single-threaded embedding).  ``submit`` returns a
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import OrderedDict, defaultdict
@@ -69,6 +70,7 @@ from repro.query.expr import (
     canonical_key,
     column_refs,
 )
+from repro.storage import member_stats_info
 
 __all__ = ["Overloaded", "QueryServer", "shape_bucket"]
 
@@ -97,6 +99,12 @@ _G_QWAIT = _obs.REGISTRY.histogram(
 _G_LAT = _obs.REGISTRY.histogram(
     "repro_serve_request_latency_seconds", "submit -> result resolution",
 )
+
+#: process-wide request ids: each distinct admitted query takes the next
+#: one; its futures carry it as ``rid`` and a traced ``serve_batch`` span
+#: lists its batch's ids, so a request can be found in the span trees and
+#: on the profiler's trace
+_REQUEST_IDS = itertools.count()
 
 
 class Overloaded(RuntimeError):
@@ -150,7 +158,8 @@ class _Pending:
 
     ``futures`` holds ``(future, t_submit)`` pairs so resolution can
     observe each waiter's end-to-end latency; ``t_submit`` is the first
-    waiter's enqueue time (the queue-wait clock)."""
+    waiter's enqueue time (the queue-wait clock); ``rid`` is the request
+    id."""
 
     query: Query  # member-bound expression
     ckey: tuple
@@ -158,6 +167,7 @@ class _Pending:
     cols: frozenset  # support column names (cache version vector domain)
     futures: list = field(default_factory=list)  # [(Future, t_submit), ...]
     t_submit: float = 0.0
+    rid: int = 0
 
 
 class _ResultCache:
@@ -333,6 +343,11 @@ class QueryServer:
         adds this caller to its waiter list.  Otherwise the query joins
         the pending set -- unless that set is full, in which case the
         request is shed with :class:`Overloaded`.
+
+        The future's ``rid`` is the request id of the query it waits on
+        (a joined waiter shares the in-flight query's), listed in the
+        ``rids`` of the traced ``serve_batch`` span that answers it; None
+        for a cache hit.
         """
         backend = backend or self.backend
         t_sub = time.perf_counter()
@@ -343,6 +358,7 @@ class QueryServer:
             ckey = canonical_key(q)
             cols = column_refs(q) or frozenset(self._names())
         fut: Future = Future()
+        fut.rid = None  # a cache hit joins no batch
         with self._lock:
             self._count("requests")
             if self._cache is not None:
@@ -357,6 +373,7 @@ class QueryServer:
             inflight = self._pending.get(key) or self._inflight.get(key)
             if inflight is not None:
                 self._count("dedup_hits")
+                fut.rid = inflight.rid
                 inflight.futures.append((fut, t_sub))
                 return fut
             if len(self._pending) >= self.max_pending:
@@ -365,9 +382,10 @@ class QueryServer:
                     f"pending queue full ({self.max_pending} distinct queries "
                     "in flight); retry later"
                 )
+            fut.rid = next(_REQUEST_IDS)
             self._pending[key] = _Pending(
                 query=q, ckey=ckey, backend=backend, cols=cols,
-                futures=[(fut, t_sub)], t_submit=t_sub,
+                futures=[(fut, t_sub)], t_submit=t_sub, rid=fut.rid,
             )
             self._work.notify()
         return fut
@@ -433,12 +451,18 @@ class QueryServer:
 
     def _dispatch(self, idx, versions, items, backend) -> int:
         t0 = time.perf_counter()
+        wait_s = 0.0
         for p in items:
-            self._observe_queue_wait(max(0.0, t0 - p.t_submit))
+            wait = max(0.0, t0 - p.t_submit)
+            self._observe_queue_wait(wait)
+            wait_s += wait
         try:
+            traced = _trace.enabled
             with _trace.span(
                 "serve_batch", batch=len(items),
                 backend=backend if backend is not None else "planner",
+                rids=[p.rid for p in items] if traced else None,
+                queue_wait_s=wait_s if traced else None,
             ):
                 outs = idx.execute_many([p.query for p in items], backend=backend)
                 outs = [
@@ -502,7 +526,8 @@ class QueryServer:
                 if self._stop and not self._pending:
                     return
             if self.window > 0:
-                time.sleep(self.window)  # let concurrent clients pile in
+                with _trace.annotation("coalesce"):
+                    time.sleep(self.window)  # let concurrent clients pile in
             while self.pump():  # drain every accumulated micro-batch before
                 pass            # sleeping another window
 
@@ -529,8 +554,8 @@ class QueryServer:
         """Serving counters: requests/served/cache_hits/dedup_hits/shed/
         executed/batches/invalidations/errors, the batch-size histogram,
         cache + pending occupancy, latency/queue-wait percentiles,
-        plan-memo counters, and the calibration constants currently
-        steering the planner.
+        plan-memo and member-statistics cache counters, and the
+        calibration constants currently steering the planner.
 
         A view over the server's metrics registry (:attr:`obs`): the same
         numbers export as Prometheus text via ``server.obs``, and mirror
@@ -557,6 +582,7 @@ class QueryServer:
             "p99_s": qw.quantile(0.99),
         }
         out["plan_memo"] = plan_memo_info()
+        out["member_stats"] = member_stats_info()
         calib = self.calibration
         out["calibration"] = None if calib is None else {
             "device": calib.device,

@@ -32,6 +32,7 @@ from .tilestore import (
     ColumnStats,
     MemberStats,
     TileStore,
+    member_stats_info,
 )
 from .tiled import run_tiled_circuit
 
@@ -43,6 +44,7 @@ __all__ = [
     "TileStore",
     "ColumnStats",
     "MemberStats",
+    "member_stats_info",
     "TILE_ZERO",
     "TILE_ONE",
     "TILE_DIRTY",

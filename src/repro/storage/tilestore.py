@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bitmaps import WORD_DTYPE, n_words_for, pack
+from repro.obs import trace as _trace
 
 from .containers import (
     CONT_DENSE,
@@ -68,9 +69,21 @@ __all__ = [
     "ColumnStats",
     "MemberStats",
     "TileStore",
+    "member_stats_info",
 ]
 
 TILE_ZERO, TILE_ONE, TILE_DIRTY, TILE_RUN = 0, 1, 2, 3
+
+#: process-wide hit/miss counts of ``TileStore.member_stats``'s per-subset
+#: cache (ad-hoc queries name new member subsets, so each one misses)
+_MEMBER_STATS_INFO = {"hits": 0, "misses": 0}
+
+
+def member_stats_info() -> dict:
+    """Process-wide member-statistics cache counters (surfaced by
+    ``QueryServer.info()`` beside the plan memo's)."""
+    return dict(_MEMBER_STATS_INFO)
+
 
 def _signature_counts(cls: np.ndarray, *, return_inverse: bool = False):
     """Distinct per-tile class signatures of ``cls`` ([members, n_tiles]).
@@ -1101,10 +1114,21 @@ class TileStore:
         key = None if slots is None else tuple(slots)
         cached = self._member_stats_cache.get(key)
         if cached is not None:
+            _MEMBER_STATS_INFO["hits"] += 1
+            if _trace.enabled:
+                _trace.current_span().set(stats_cache="hit")
             return cached
+        _MEMBER_STATS_INFO["misses"] += 1
         idx = np.arange(self.n) if slots is None else np.asarray(list(key))
         if idx.size == 0:
             return MemberStats(0, self.n_words, self.tile_words, 1.0, 0.0, 0, 0)
+        with _trace.span("member_stats", members=int(idx.size),
+                         tiles=int(self.n_tiles)):
+            stats = self._member_stats(idx)
+        self._member_stats_cache[key] = stats
+        return stats
+
+    def _member_stats(self, idx: np.ndarray) -> MemberStats:
         cls = self._classes_word[idx]
         dirty_tiles = int((cls >= TILE_DIRTY).sum())
         dens = [self._cols[i].cardinality / max(self.r, 1) for i in idx]
@@ -1114,7 +1138,7 @@ class TileStore:
             for sig, cnt in zip(sigs, counts)
         )
         kinds = self.container_kinds[idx]
-        stats = MemberStats(
+        return MemberStats(
             n=int(idx.size),
             n_words=self.n_words,
             tile_words=self.tile_words,
@@ -1130,5 +1154,3 @@ class TileStore:
             ),
             compressed_words=int(self.storage_words_cell[idx].sum()),
         )
-        self._member_stats_cache[key] = stats
-        return stats

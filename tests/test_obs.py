@@ -17,6 +17,7 @@ The acceptance bar:
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 
@@ -41,6 +42,8 @@ from repro.query import (
 )
 from repro.query.execinfo import EXEC_INFO_SCHEMA, make_exec_info, merge_exec_infos
 from repro.serve import QueryServer
+from repro.serve.frontend import _REQUEST_IDS
+from repro.storage import member_stats_info
 
 N = 10
 TILE_BITS = 64 * 32
@@ -310,7 +313,33 @@ def test_threaded_server_counts_survive_concurrency(idx):
 
 # -- disabled mode: zero mutations -------------------------------------------
 
-def test_disabled_mode_mutates_nothing(idx):
+class _RecordedAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit with its thread, and keeps every annotation made."""
+
+    log: list = []
+    made: list = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+        self.made.append(self)
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+@pytest.fixture
+def recorded_annotations(monkeypatch):
+    _RecordedAnnotation.log, _RecordedAnnotation.made = [], []
+    monkeypatch.setattr(trace, "_TraceAnnotation", _RecordedAnnotation)
+    return _RecordedAnnotation.log
+
+
+def test_disabled_mode_mutates_nothing(idx, recorded_annotations):
     # warm every lazy import + registration the measured calls would do
     obs.enable()
     idx.execute(Interval(2, 8))
@@ -318,16 +347,218 @@ def test_disabled_mode_mutates_nothing(idx):
         server.serve_many([Threshold(3)])
     obs.disable()
     obs.reset()
+    recorded_annotations.clear()
     before = json.dumps(obs.REGISTRY.snapshot(), sort_keys=True, default=str)
+    stats_before = member_stats_info()
+    first_rid = next(_REQUEST_IDS)
     for _ in range(5):
         idx.execute(Interval(2, 8))
         idx.execute(Threshold(4), backend="tiled_fused")
+    with QueryServer(idx, window=0.001) as server:  # batcher thread: coalesces
+        server.serve_many([Threshold(3), Interval(2, 6)])
     after = json.dumps(obs.REGISTRY.snapshot(), sort_keys=True, default=str)
     assert before == after
     assert obs.last_trace() is None
     assert obs.drift_samples() == 0
     assert trace.span("anything") is trace.NULL_SPAN
     assert trace.current_span() is trace.NULL_SPAN
+    # no profiler annotation (spans, coalescing sleep) and no gc hook
+    assert trace.annotation("coalesce") is trace.NULL_SPAN
+    assert recorded_annotations == []
+    assert obs._gc_hook not in gc.callbacks
+    # the member-statistics counters are plain ints (cache hits only here:
+    # every subset was planned in the warm-up) and request ids one
+    # integer per admitted query
+    stats_after = member_stats_info()
+    assert stats_after["misses"] == stats_before["misses"]
+    assert stats_after["hits"] > stats_before["hits"]
+    assert all(type(v) is int for v in stats_after.values())
+    assert next(_REQUEST_IDS) == first_rid + 3
+
+
+# -- the profiler clock ---------------------------------------------------------
+
+def test_span_emits_profiler_annotation_only_while_enabled(recorded_annotations):
+    with trace.span("plan"):
+        pass
+    assert recorded_annotations == []
+    obs.enable()
+    with trace.span("execute"):
+        with trace.span("plan") as sp:
+            sp.set(memo="hit")
+    obs.disable()
+    me = threading.get_ident()
+    assert recorded_annotations == [
+        ("enter", "repro:execute", me), ("enter", "repro:plan", me),
+        ("exit", "repro:plan", me), ("exit", "repro:execute", me),
+    ]
+    # a span opened on another thread is annotated on that thread
+    recorded_annotations.clear()
+    obs.enable()
+    worker = threading.Thread(target=lambda: trace.span("coalesce").__enter__().__exit__())
+    worker.start()
+    worker.join()
+    obs.disable()
+    assert {t for _, _, t in recorded_annotations} == {worker.ident}
+    assert [n for _, n, _ in recorded_annotations] == ["repro:coalesce"] * 2
+
+
+def test_disable_removes_gc_hook(recorded_annotations):
+    obs.enable()
+    obs.enable()  # idempotent: one hook
+    assert gc.callbacks.count(obs._gc_hook) == 1
+    gc.collect()
+    gc_notes = [x for x in recorded_annotations if x[1] == "repro:gc"]
+    assert [x[0] for x in gc_notes] == ["enter", "exit"]
+    obs.disable()
+    assert obs._gc_hook not in gc.callbacks
+    recorded_annotations.clear()
+    gc.collect()
+    assert recorded_annotations == []
+
+
+def test_spans_and_gc_land_on_the_profiler_trace(idx, tmp_path):
+    """A real profiler trace (CPU) holds the spans and a collection as
+    ``repro:`` events on the host plane, the collection with its
+    generation and a served batch with its request ids."""
+    import jax
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        idx.execute(Interval(2, 8))
+        gc.collect()
+        server = QueryServer(idx, cache_entries=0)
+        fut = server.submit(Threshold(3))
+        server.pump()
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("repro:"):
+                        events.setdefault(ev.name, []).extend(ev.stats)
+    assert {"repro:execute", "repro:plan", "repro:dispatch", "repro:gc"} <= set(events)
+    assert ("generation", 2) in events["repro:gc"]
+    assert ("rids", f"[{fut.rid}]") in events["repro:serve_batch"]
+
+
+# -- member statistics, request identity, batched dense accounting -------------
+
+def test_member_stats_span_opens_only_on_cache_miss():
+    bits = _bits(seed=5)
+    fresh = BitmapIndex.from_dense(bits, names=[f"s{i}" for i in range(N)])
+    q = Threshold(2, over=("s0", "s3", "s5"))
+    start = member_stats_info()
+    obs.enable()
+    fresh.explain(q)
+    first = obs.last_trace()
+    fresh.explain(q)
+    second = obs.last_trace()
+    obs.disable()
+    assert first.name == "plan" and second.name == "plan"
+    ms = first.find("member_stats")
+    assert ms is not None
+    assert ms.attrs == {"members": 3, "tiles": fresh.store.n_tiles}
+    assert "stats_cache" not in first.attrs
+    assert ms.wall_s <= first.wall_s
+    # the hit rides the plan span; no zero-length child
+    assert second.find("member_stats") is None
+    assert second.attrs["stats_cache"] == "hit"
+    end = member_stats_info()
+    assert end["misses"] - start["misses"] == 1
+    assert end["hits"] - start["hits"] == 1
+
+
+def test_serve_batch_request_ids_join_the_client_futures(idx, recorded_annotations):
+    """Each client's future names its request id; exactly one traced
+    ``serve_batch`` span lists it, in the span tree and on the profiler
+    annotation alike, beside the queue wait of its requests; a cache hit
+    joins no batch."""
+    batches = []
+
+    def on_root(sp):
+        if sp.name == "serve_batch":
+            batches.append(sp)
+
+    def waits():
+        fam = obs.REGISTRY.snapshot()["repro_serve_queue_wait_seconds"]
+        states = fam["samples"].values()
+        return (sum(st["count"] for st in states), sum(st["sum"] for st in states))
+
+    trace.add_root_listener(on_root)
+    obs.enable()
+    waits_before = waits()
+    try:
+        pool = [Interval(2, 6), Threshold(2, over=("s0", "s3", "s6")),
+                And(Col("s1"), Not(Col("s2"))), Threshold(3), Interval(1, 9)]
+        with QueryServer(idx, window=0.002, cache_entries=0) as server:
+            futs = []
+
+            def client(ci):
+                for j in range(3):
+                    fut = server.submit(pool[(ci + j) % len(pool)])
+                    fut.result(30)
+                    futs.append(fut)
+
+            threads = [threading.Thread(target=client, args=(ci,))
+                       for ci in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            executed = server.info()["executed"]
+        with QueryServer(idx, window=0) as cached:
+            cached.serve_many([Threshold(4)])
+            hit = cached.submit(Threshold(4))
+    finally:
+        obs.disable()
+        trace._ROOT_LISTENERS.remove(on_root)
+    rids = [r for sp in batches for r in sp.attrs["rids"]]
+    assert len(set(rids)) == len(rids)
+    assert all(type(r) is int for r in rids)
+    assert sum(sp.attrs["batch"] for sp in batches) == len(rids) == executed + 1
+    # one queue wait observed per request a batch executed; each span
+    # carries its batch's share of them
+    count, total = waits()
+    assert count - waits_before[0] == len(rids)
+    assert all(sp.attrs["queue_wait_s"] >= 0 for sp in batches)
+    assert sum(sp.attrs["queue_wait_s"] for sp in batches) == pytest.approx(
+        total - waits_before[1], rel=1e-9)
+    for sp in batches:
+        assert len(sp.attrs["rids"]) == sp.attrs["batch"]
+    assert len(futs) == 12 and all(f.rid in rids for f in futs)
+    assert hit.done() and hit.rid is None
+    # the profiler annotation carries the span's ids
+    notes = [a.attrs["rids"] for a in _RecordedAnnotation.made
+             if a.name == "repro:serve_batch"]
+    assert notes == [sp.attrs["rids"] for sp in batches]
+
+
+def test_batched_dense_execute_many_span_words_match_exec_info(idx, data):
+    _, counts = data
+    qs = [Threshold(3), Threshold(5), Interval(2, 6)]
+    obs.enable()
+    outs = idx.execute_many(qs, backend="circuit")
+    obs.disable()
+    np.testing.assert_array_equal(np.asarray(unpack(outs[0], idx.r)), counts >= 3)
+    root = obs.last_trace()
+    assert root.name == "execute_many"
+    disp = [s for s in root.iter() if s.name == "dispatch"]
+    assert len(disp) == 1 and disp[0].attrs["batched"] == len(qs)
+    info = idx.last_info
+    assert info["n_outputs"] == len(qs)
+    assert disp[0].attrs["measured_words"] == info["words_touched"]
+    assert disp[0].attrs["dirty_words_gathered"] == info["dirty_words_gathered"]
+    assert disp[0].attrs["words_by_kind"] == info["words_by_kind"]
+    assert root.attrs["measured_words"] == info["words_touched"]
+    nw = idx.n_words
+    assert info["words_touched"] == (N + len(qs)) * nw
 
 
 # -- drift accounting ---------------------------------------------------------
@@ -380,7 +611,10 @@ def test_prometheus_export_lints_clean_and_jsonl_parses(idx):
     problems = lint_prometheus(prom)
     obs.disable()
     assert problems == []
-    assert "repro_query_wall_seconds" in prom
+    # host enqueue time is not query wall time: the spans hold host time
+    # and the serve latency histogram the request's
+    assert "repro_query_words_touched" in prom
+    assert "repro_query_wall_seconds" not in prom
     for line in obs.export_jsonl().strip().splitlines():
         fam = json.loads(line)
         assert {"name", "type", "samples"} <= set(fam)
