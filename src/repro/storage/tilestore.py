@@ -74,9 +74,14 @@ __all__ = [
 
 TILE_ZERO, TILE_ONE, TILE_DIRTY, TILE_RUN = 0, 1, 2, 3
 
-#: process-wide hit/miss counts of ``TileStore.member_stats``'s per-subset
-#: cache (ad-hoc queries name new member subsets, so each one misses)
-_MEMBER_STATS_INFO = {"hits": 0, "misses": 0}
+#: process-wide counts of ``TileStore.member_stats``: hits and misses of
+#: its per-subset cache (ad-hoc queries name new member subsets, so each
+#: one misses), and over the misses the members answered from the
+#: per-column tables alone (``folded_members``) and those whose class
+#: varies across tiles and needed the per-tile signature pass
+#: (``keyed_members``)
+_MEMBER_STATS_INFO = {"hits": 0, "misses": 0, "folded_members": 0,
+                      "keyed_members": 0}
 
 
 def member_stats_info() -> dict:
@@ -102,6 +107,23 @@ def _signature_counts(cls: np.ndarray, *, return_inverse: bool = False):
     )
     sigs = uniq.view(np.uint8).reshape(uniq.size, rows.shape[1])
     return sigs, second
+
+
+@dataclasses.dataclass(frozen=True)
+class _MemberTables:
+    """Per-column inputs of :meth:`TileStore.member_stats`, built once per
+    store: every field of :class:`MemberStats` but the tile-level ones is
+    a sum of these over the members, and the tile-level ones need a
+    per-tile pass only over the members whose class varies."""
+
+    dirty: np.ndarray  # int64[N] dirty tiles
+    containers: np.ndarray  # int64[N, 3] dense / sparse / run containers
+    words: np.ndarray  # int64[N] compressed words stored
+    density: np.ndarray  # float64[N] cardinality / r
+    const: np.ndarray  # uint8[N] the class of every tile, else _VARYING
+
+
+_VARYING = 255  # _MemberTables.const of a column whose class varies
 
 
 if hasattr(np, "bitwise_count"):  # numpy >= 2.0
@@ -363,6 +385,8 @@ class TileStore:
         # the np.unique signature pass) per member subset never changes --
         # planners hit this once per (shard, subset), not once per query
         self._member_stats_cache: dict = {}
+        # the per-column tables behind it, built on its first miss
+        self._member_tables_cache: _MemberTables | None = None
 
     # -- legacy densified dirty surface ------------------------------------
     def _assemble_dirty(self) -> None:
@@ -725,6 +749,7 @@ class TileStore:
         store._refined_classes = None
         store._col_stats = None
         store._member_stats_cache = {}
+        store._member_tables_cache = None
         if not (kinds > CONT_DENSE).any():
             # all-dense layout: the densified dirty pack IS the dense pack
             # (same per-column tile order), so the legacy device path reads
@@ -1123,34 +1148,67 @@ class TileStore:
         if idx.size == 0:
             return MemberStats(0, self.n_words, self.tile_words, 1.0, 0.0, 0, 0)
         with _trace.span("member_stats", members=int(idx.size),
-                         tiles=int(self.n_tiles)):
-            stats = self._member_stats(idx)
+                         tiles=int(self.n_tiles)) as sp:
+            stats, keyed = self._member_stats(idx)
+            sp.set(varying=keyed)
+        _MEMBER_STATS_INFO["folded_members"] += int(idx.size) - keyed
+        _MEMBER_STATS_INFO["keyed_members"] += keyed
         self._member_stats_cache[key] = stats
         return stats
 
-    def _member_stats(self, idx: np.ndarray) -> MemberStats:
-        cls = self._classes_word[idx]
-        dirty_tiles = int((cls >= TILE_DIRTY).sum())
-        dens = [self._cols[i].cardinality / max(self.r, 1) for i in idx]
-        sigs, counts = _signature_counts(cls)
-        signatures = tuple(
-            (int(cnt), int((sig == TILE_ONE).sum()), int((sig >= TILE_DIRTY).sum()))
-            for sig, cnt in zip(sigs, counts)
-        )
-        kinds = self.container_kinds[idx]
-        return MemberStats(
+    def _member_tables(self) -> _MemberTables:
+        if self._member_tables_cache is None:
+            cls = self._classes_word
+            kinds = self.container_kinds
+            lo = cls.min(axis=1, initial=TILE_DIRTY)
+            hi = cls.max(axis=1, initial=TILE_ZERO)
+            self._member_tables_cache = _MemberTables(
+                dirty=(cls >= TILE_DIRTY).sum(axis=1, dtype=np.int64),
+                containers=np.stack(
+                    [(kinds == k).sum(axis=1, dtype=np.int64)
+                     for k in (CONT_DENSE, CONT_SPARSE, CONT_RUN)], axis=1
+                ),
+                words=self.storage_words_cell.sum(axis=1, dtype=np.int64),
+                density=np.array(
+                    [c.cardinality / max(self.r, 1) for c in self._cols],
+                    np.float64,
+                ),
+                const=np.where(lo == hi, lo, _VARYING).astype(np.uint8),
+            )
+        return self._member_tables_cache
+
+    def _member_stats(self, idx: np.ndarray) -> tuple:
+        """``(MemberStats, number of members keyed per tile)`` of ``idx``.
+
+        Members whose class is the same on every tile are folded in from
+        the per-column tables; only the rest are keyed tile by tile.  A
+        constant member is equal on every tile, so it only adds to each
+        signature's ``n_one``/``n_dirty`` and leaves their order alone."""
+        t = self._member_tables()
+        const = t.const[idx]
+        varying = idx[const == _VARYING]
+        n_one = int((const == TILE_ONE).sum())
+        n_dirty = int((const == TILE_DIRTY).sum())
+        if varying.size:
+            sigs, counts = _signature_counts(self._classes_word[varying])
+            signatures = tuple(
+                (int(cnt), n_one + int((sig == TILE_ONE).sum()),
+                 n_dirty + int((sig >= TILE_DIRTY).sum()))
+                for sig, cnt in zip(sigs, counts)
+            )
+        else:
+            signatures = ((self.n_tiles, n_one, n_dirty),)
+        dirty_tiles = int(t.dirty[idx].sum())
+        stats = MemberStats(
             n=int(idx.size),
             n_words=self.n_words,
             tile_words=self.tile_words,
-            clean_fraction=1.0 - dirty_tiles / max(cls.size, 1),
-            density=float(np.mean(dens)),
+            clean_fraction=1.0 - dirty_tiles / max(idx.size * self.n_tiles, 1),
+            density=float(np.mean(t.density[idx])),
             dirty_words=dirty_tiles * self.tile_words,
-            case3_tiles=int(((cls >= TILE_DIRTY).any(axis=0)).sum()),
+            case3_tiles=sum(cnt for cnt, _, d in signatures if d),
             signatures=signatures,
-            container_tiles=(
-                int((kinds == CONT_DENSE).sum()),
-                int((kinds == CONT_SPARSE).sum()),
-                int((kinds == CONT_RUN).sum()),
-            ),
-            compressed_words=int(self.storage_words_cell[idx].sum()),
+            container_tiles=tuple(t.containers[idx].sum(axis=0).tolist()),
+            compressed_words=int(t.words[idx].sum()),
         )
+        return stats, int(varying.size)

@@ -464,7 +464,8 @@ def test_member_stats_span_opens_only_on_cache_miss():
     assert first.name == "plan" and second.name == "plan"
     ms = first.find("member_stats")
     assert ms is not None
-    assert ms.attrs == {"members": 3, "tiles": fresh.store.n_tiles}
+    # s0 has clean tiles beside dirty ones; s3 and s5 are dirty throughout
+    assert ms.attrs == {"members": 3, "tiles": fresh.store.n_tiles, "varying": 1}
     assert "stats_cache" not in first.attrs
     assert ms.wall_s <= first.wall_s
     # the hit rides the plan span; no zero-length child
@@ -473,6 +474,8 @@ def test_member_stats_span_opens_only_on_cache_miss():
     end = member_stats_info()
     assert end["misses"] - start["misses"] == 1
     assert end["hits"] - start["hits"] == 1
+    assert end["folded_members"] - start["folded_members"] == 2
+    assert end["keyed_members"] - start["keyed_members"] == 1
 
 
 def test_serve_batch_request_ids_join_the_client_futures(idx, recorded_annotations):

@@ -3,6 +3,7 @@
 scancount oracle, planner cost model, stats-cache fix, shim deprecation."""
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,7 +21,9 @@ from repro.storage import (
     TILE_ONE,
     TILE_RUN,
     TILE_ZERO,
+    MemberStats,
     TileStore,
+    member_stats_info,
     run_max_intervals,
     run_tiled_circuit,
     sparse_max_positions,
@@ -172,6 +175,141 @@ def test_member_stats_per_subset_not_index_mean():
     assert store.member_stats([1]).clean_fraction == 0.0
     assert 0.0 < store.member_stats(None).clean_fraction < 1.0
     assert store.member_stats([0]).dirty_words == 0
+
+
+def _member_stats_oracle(store, slots):
+    """The per-tile formula the per-column tables replaced: every field
+    from a gather of the members' [m, n_tiles] classes and containers,
+    the signatures from numpy's axis-unique rather than the store's own
+    signature pass."""
+    idx = np.arange(store.n) if slots is None else np.asarray(list(slots))
+    if idx.size == 0:
+        return MemberStats(0, store.n_words, store.tile_words, 1.0, 0.0, 0, 0)
+    cls = store.classes_word[idx]
+    dirty_tiles = int((cls >= TILE_DIRTY).sum())
+    dens = [store.cardinalities[i] / max(store.r, 1) for i in idx]
+    sigs, counts = np.unique(cls.T, axis=0, return_counts=True)
+    kinds = store.container_kinds[idx]
+    return MemberStats(
+        n=int(idx.size),
+        n_words=store.n_words,
+        tile_words=store.tile_words,
+        clean_fraction=1.0 - dirty_tiles / max(cls.size, 1),
+        density=float(np.mean(dens)),
+        dirty_words=dirty_tiles * store.tile_words,
+        case3_tiles=int(((cls >= TILE_DIRTY).any(axis=0)).sum()),
+        signatures=tuple(
+            (int(cnt), int((sig == TILE_ONE).sum()), int((sig >= TILE_DIRTY).sum()))
+            for sig, cnt in zip(sigs, counts)
+        ),
+        container_tiles=(
+            int((kinds == CONT_DENSE).sum()),
+            int((kinds == CONT_SPARSE).sum()),
+            int((kinds == CONT_RUN).sum()),
+        ),
+        compressed_words=int(store.storage_words_cell[idx].sum()),
+    )
+
+
+def _member_bits(seed, shares, n=64, n_tiles=12):
+    """Columns that are constant-ZERO, constant-ONE, constant-DIRTY or
+    varying with the given ``shares``; a dirty tile is a sparse, a run or
+    a dense container."""
+    rng = np.random.default_rng(seed)
+    bits = np.zeros((n, n_tiles * SPAN), bool)
+    for i, kind in enumerate(rng.choice(4, n, p=shares)):
+        for t in range(n_tiles):
+            cls = rng.integers(3) if kind == 3 else kind
+            tile = bits[i, t * SPAN:(t + 1) * SPAN]
+            if cls == TILE_ONE:
+                tile[:] = True
+            elif cls == TILE_DIRTY:
+                container = rng.integers(3)
+                if container == 0:
+                    tile[rng.choice(SPAN, rng.integers(1, 100), replace=False)] = True
+                elif container == 1:
+                    lo, hi = np.sort(rng.choice(np.arange(1, SPAN), 2, replace=False))
+                    tile[lo:hi] = True
+                else:
+                    tile[:] = rng.random(SPAN) < 0.4
+    return bits
+
+
+_MIXED = (0.15, 0.15, 0.2, 0.5)
+
+
+@pytest.fixture(scope="module")
+def member_stores():
+    """Stores from every constructor; derived ones are made after their
+    base answered once, so stale per-column tables would show."""
+    mixed = _store_of(_member_bits(41, _MIXED))
+    varying = _store_of(_member_bits(42, (0.0, 0.0, 0.0, 1.0)))
+    rows = pack(jnp.asarray(_member_bits(43, _MIXED)))
+    base = TileStore.from_packed(rows[:-1])
+    swap = pack(jnp.asarray(_member_bits(44, (0.0, 0.0, 0.0, 1.0), n=1)))[0]
+    for s in (mixed, varying, base):
+        s.member_stats(None)
+    arrays = {"classes": mixed.classes_word, "kinds": mixed.container_kinds,
+              "cardinalities": np.asarray(mixed.cardinalities, np.int64),
+              **mixed.packs}
+    return {
+        "mixed": mixed,
+        "varying": varying,
+        "from_arrays": TileStore.from_arrays(
+            arrays, tile_words=mixed.tile_words, n_words=mixed.n_words,
+            r=mixed.r, containers=mixed.containers),
+        "slice_tiles": mixed.slice_tiles(3, 10),
+        "append": base.append(rows[-1]),
+        "replace": mixed.replace(5, swap),
+        "on_device": mixed.on_device(jax.devices()[0]),
+    }
+
+
+@pytest.mark.parametrize("size", [0, 1, 13, 31, 33, 64, None])
+@pytest.mark.parametrize("kind", ["mixed", "varying", "from_arrays",
+                                  "slice_tiles", "append", "replace",
+                                  "on_device"])
+def test_member_stats_match_the_per_tile_formula(member_stores, kind, size):
+    """Per-column tables, with a per-tile pass over the varying members
+    only, give exactly the old per-tile answer, signature order included,
+    for unsorted subsets of every size."""
+    store = member_stores[kind]
+    slots = (None if size is None
+             else np.random.default_rng(size).permutation(store.n)[:size].tolist())
+    assert store.member_stats(slots) == _member_stats_oracle(store, slots)
+
+
+class _TilePass(Exception):
+    pass
+
+
+class _NoRowGather:
+    """A [N, n_tiles] table whose rows may not be gathered."""
+
+    def __getitem__(self, key):
+        raise _TilePass(key)
+
+
+def test_constant_members_fold_without_a_tile_pass():
+    store = _store_of(_member_bits(45, _MIXED))
+    store.member_stats(None)  # builds the per-column tables
+    cls = store.classes_word
+    const = [i for i in range(store.n) if (cls[i] == cls[i, 0]).all()]
+    varying = sorted(set(range(store.n)) - set(const))
+    assert {int(cls[i, 0]) for i in const} == {TILE_ZERO, TILE_ONE, TILE_DIRTY}
+    subset = const[::-1]
+    expected = _member_stats_oracle(store, subset)
+    assert len(expected.signatures) == 1
+    store._classes_word = _NoRowGather()
+    store._kinds_cache = _NoRowGather()
+    store._storage_words_cell = _NoRowGather()
+    start = member_stats_info()
+    assert store.member_stats(subset) == expected
+    end = member_stats_info()
+    assert end["folded_members"] - start["folded_members"] == len(subset)
+    assert end["keyed_members"] == start["keyed_members"]
+    with pytest.raises(_TilePass):  # a varying member needs the tile pass
+        store.member_stats(const[:3] + varying[:1])
 
 
 # ---------------------------------------------------------------------------
