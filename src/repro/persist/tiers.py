@@ -18,11 +18,12 @@ that hole:
   * metadata (classes, kinds, stats, cardinalities) passes straight
     through -- it is tiny and already resident.
 
-Dense-path backends still work (``densify()`` delegates) but count as
-``full_materializations`` in :meth:`cache_info` -- if that number is
-nonzero the index is too dense-hot for paging and should be loaded with
-``to_device=True`` instead.  Plan with ``tiled_fused`` (the planner does
-so on its own whenever tile-skipping pays) to stay on the paged path.
+Dense-path backends still work (``densify()`` and ``planes()``
+delegate) but count as ``full_materializations`` in :meth:`cache_info`
+-- if that number is nonzero the index is too dense-hot for paging and
+should be loaded with ``to_device=True`` instead.  Plan with
+``tiled_fused`` (the planner does so on its own whenever tile-skipping
+pays) to stay on the paged path.
 """
 from __future__ import annotations
 
@@ -168,6 +169,11 @@ class PagedTileStore:
         self.full_materializations += 1
         _PAGE_EVENTS.inc(1, event="densify")
         return self._base.densify()
+
+    def planes(self):
+        self.full_materializations += 1
+        _PAGE_EVENTS.inc(1, event="densify")
+        return self._base.planes()
 
     def column(self, i: int):
         return self.densify()[int(i)]

@@ -47,6 +47,7 @@ from .compile import build_query_circuit
 from .executors import (
     THRESHOLD_BACKENDS,
     ShardContext,
+    bare_members_info,
     run_plan,
     run_threshold_backend,
 )
@@ -86,6 +87,7 @@ __all__ = [
     "compiled_cache_info",
     "clear_compiled_cache",
     "plan_memo_info",
+    "bare_members_info",
     "bind_members",
     "canonical_key",
     "column_refs",

@@ -37,6 +37,9 @@ EXEC_INFO_SCHEMA: dict[str, tuple] = {
     #: how the device evaluated the circuit: "pallas" (a Pallas kernel,
     #: Mosaic-compiled on TPU, interpreted elsewhere) or "xla" (jnp code)
     "kernel": (_LABEL, ""),
+    #: how a bare threshold read its members: "planes" (in place, from
+    #: the store's plane view) or "rows" (gathered from the dense view)
+    "members": (_LABEL, ""),
     "n_tiles": (_SUM, 0),
     "selected_tiles": (_SUM, 0),
     "n_outputs": (_MAX, 1),

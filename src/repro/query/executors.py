@@ -6,7 +6,9 @@ planner produced ``wide_or`` / ``rbmrg_block`` / ``dsk`` names that
 
   * device circuit family  -- scancount, scancount_streaming, looped,
     csvckt, ssum, treeadd, srtckt, sopckt (straight-line XLA bitwise code)
-  * fused                  -- the Pallas kernel (interpret mode off-TPU)
+  * fused                  -- the Pallas kernel (interpret mode off-TPU);
+    a bare threshold's kernel reads its members in place from the
+    store's plane view (``TileStore.planes``), with no gather
   * tiled_fused            -- the storage engine's tile-skipping executor:
     clean tiles resolve as constants before launch, only dirty tiles are
     gathered into the fused kernel (repro.storage.run_tiled_circuit)
@@ -27,6 +29,7 @@ hand each shard a different plan.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from functools import partial
 from typing import Callable
 
@@ -41,6 +44,7 @@ from repro.query.execinfo import make_exec_info
 __all__ = [
     "THRESHOLD_BACKENDS",
     "ShardContext",
+    "bare_members_info",
     "run_plan",
     "run_threshold_backend",
 ]
@@ -53,6 +57,25 @@ _DEVICE_ALGOS = (
 THRESHOLD_BACKENDS = _DEVICE_ALGOS + (
     "fused", "tiled_fused", "wide_or", "wide_and", "rbmrg_block", "dsk",
 )
+
+
+#: process-wide counts of bare-threshold executions by how the members
+#: were read: in place from the store's plane view by the fused kernel
+#: (``planes``), or gathered as rows of the dense view (``rows``)
+_BARE_MEMBERS = {"planes": 0, "rows": 0}
+_BARE_MEMBERS_LOCK = threading.Lock()
+
+
+def _count_bare(members: str) -> None:
+    with _BARE_MEMBERS_LOCK:
+        _BARE_MEMBERS[members] += 1
+
+
+def bare_members_info() -> dict:
+    """Bare-threshold executions by member read (surfaced by
+    ``QueryServer.info()``)."""
+    with _BARE_MEMBERS_LOCK:
+        return dict(_BARE_MEMBERS)
 
 
 @partial(jax.jit, static_argnames=("t", "algorithm"))
@@ -120,7 +143,7 @@ class ShardContext:
     tiled_engine: str | None = None
 
     def member_rows(self) -> jax.Array:
-        """Dense rows of the bare-threshold member subset."""
+        """Dense rows of the bare-threshold member subset (gathered)."""
         rows = self.dense()
         slots = self.bare[0]
         if slots is not None:
@@ -129,11 +152,12 @@ class ShardContext:
 
 
 def _dense_exec_info(backend: str, engine: str, n_rows: int, out,
-                     launches: int = 1) -> dict:
+                     launches: int = 1, members: str = "") -> dict:
     """ExecInfo for a backend that reads every member row densely.
 
     ``words_touched`` is the roofline traffic term: N input rows read plus
-    each output row written, all at the shard's word width.
+    each output row written, all at the shard's word width.  Members read
+    in place from the plane view (``members="planes"``) gather nothing.
     """
     k = 1 if out.ndim == 1 else out.shape[0]
     nw = int(out.shape[-1])
@@ -144,10 +168,11 @@ def _dense_exec_info(backend: str, engine: str, n_rows: int, out,
         kernel="pallas" if backend == "fused" else (
             "xla" if engine == "dense" else ""
         ),
+        members=members,
         n_outputs=k,
         total_words=total,
         words_touched=total,
-        dirty_words_gathered=n_rows * nw,
+        dirty_words_gathered=0 if members == "planes" else n_rows * nw,
         words_by_kind={"dense": n_rows * nw},
         launches=launches,
         work_fraction=1.0,
@@ -184,13 +209,18 @@ def run_plan(ctx: ShardContext, plan):
             engine=ctx.tiled_engine,
         )
         return out, info
+    if alg == "fused" and ctx.bare is not None:
+        return _fused_bare(ctx)
     if alg in THRESHOLD_BACKENDS and ctx.bare is not None:
         rows = ctx.member_rows()
         out = run_threshold_backend(
             rows, ctx.bare[1], alg, block_words=ctx.block_words
         )
         engine = "host" if alg == "dsk" else "dense"
-        return out, _dense_exec_info(alg, engine, int(rows.shape[0]), out)
+        _count_bare("rows")
+        return out, _dense_exec_info(
+            alg, engine, int(rows.shape[0]), out, members="rows"
+        )
     if alg in CIRCUIT_BACKENDS:
         from repro.kernels.threshold_ssum import INTERPRET, run_circuit_cached
 
@@ -211,6 +241,26 @@ def run_plan(ctx: ShardContext, plan):
             "use 'circuit', 'fused' or 'tiled_fused' for composite expressions"
         )
     raise ValueError(f"unknown backend {alg!r}")
+
+
+def _fused_bare(ctx: ShardContext):
+    """A bare threshold through the fused kernel, its members read in
+    place from the store's plane view: the slots go to the kernel as a
+    host array, so one jitted call is the whole device work."""
+    from repro.kernels.threshold_ssum import INTERPRET, threshold_pallas
+
+    if ctx.store is None:
+        raise ValueError("'fused' bare thresholds read a tile store's plane view")
+    store = ctx.store()
+    slots, t = ctx.bare
+    slots = np.asarray(range(ctx.n) if slots is None else slots, np.int32)
+    out = threshold_pallas(
+        store.planes(), slots, t, n_words=store.n_words, interpret=INTERPRET
+    )
+    _count_bare("planes")
+    return out, _dense_exec_info(
+        "fused", "dense", int(slots.shape[0]), out, members="planes"
+    )
 
 
 def run_threshold_backend(
@@ -255,9 +305,9 @@ def run_threshold_backend(
     if backend == "dsk":
         return _dsk_threshold(bitmaps, t)
     if backend == "fused":
-        from repro.kernels.threshold_ssum import INTERPRET, threshold_pallas
+        from repro.kernels.threshold_ssum import INTERPRET, threshold_rows
 
-        return threshold_pallas(bitmaps, t, block_words=block_words, interpret=INTERPRET)
+        return threshold_rows(bitmaps, t, interpret=INTERPRET)
     if backend in _DEVICE_ALGOS:
         return _device_threshold(bitmaps, t, backend)
     raise ValueError(f"unknown algorithm {backend!r}; valid: {THRESHOLD_BACKENDS}")

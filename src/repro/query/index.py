@@ -211,6 +211,7 @@ def _annotate_dispatch(sp, info: dict) -> None:
     )
     if info.get("backend") != "tiled_fused":
         sp.set(
+            members=info.get("members") or None,
             dirty_words_gathered=info.get("dirty_words_gathered"),
             words_by_kind=dict(info.get("words_by_kind") or {}),
         )
@@ -575,7 +576,7 @@ class BitmapIndex:
             if backend is None or backend in CIRCUIT_BACKENDS:
                 for i, (q, alg) in enumerate(zip(qs, algs)):
                     if alg in CIRCUIT_BACKENDS or (
-                        alg in _BATCHABLE and self._bare_threshold(q) is not None
+                        alg in _BATCHABLE and self._bare_slots(q) is not None
                     ):
                         batch.append(i)
             results: dict[int, jax.Array] = {}
@@ -647,17 +648,6 @@ class BitmapIndex:
     def _bare_slots(self, q: Query):
         """(member slots | None, t) when q is a bare threshold, else None."""
         return bare_slots(q, self._slot)
-
-    def _bare_threshold(self, q: Query):
-        """(rows, t) when q is a Threshold over plain columns, else None."""
-        bare = self._bare_slots(q)
-        if bare is None:
-            return None
-        slots, t = bare
-        rows = self.columns
-        if slots is not None:
-            rows = rows[jnp.asarray(slots)]
-        return rows, t
 
     def _shard_ctx(self, q: Query, block_words) -> ShardContext:
         """This index's whole row space as one executor shard."""

@@ -55,7 +55,7 @@ import repro.obs as _obs
 from repro.core.calibration import get_calibration
 from repro.obs import trace as _trace
 from repro.obs.registry import MetricsRegistry
-from repro.query import plan_memo_info
+from repro.query import bare_members_info, plan_memo_info
 from repro.query.expr import (
     And,
     AndNot,
@@ -554,7 +554,8 @@ class QueryServer:
         """Serving counters: requests/served/cache_hits/dedup_hits/shed/
         executed/batches/invalidations/errors, the batch-size histogram,
         cache + pending occupancy, latency/queue-wait percentiles,
-        plan-memo and member-statistics cache counters, and the
+        plan-memo and member-statistics cache counters, bare thresholds
+        by how their members were read (``bare_members``), and the
         calibration constants currently steering the planner.
 
         A view over the server's metrics registry (:attr:`obs`): the same
@@ -583,6 +584,7 @@ class QueryServer:
         }
         out["plan_memo"] = plan_memo_info()
         out["member_stats"] = member_stats_info()
+        out["bare_members"] = bare_members_info()
         calib = self.calibration
         out["calibration"] = None if calib is None else {
             "device": calib.device,

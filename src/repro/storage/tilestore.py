@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bitmaps import WORD_DTYPE, n_words_for, pack
+from repro.kernels.threshold_ssum import host_planes
 from repro.obs import trace as _trace
 
 from .containers import (
@@ -350,7 +351,7 @@ class TileStore:
     """Tile-classified columns: classes + per-kind packed container arrays."""
 
     def __init__(self, columns: list, *, tile_words: int, n_words: int, r: int,
-                 dense=None, containers: bool = True, device=None):
+                 dense=None, planes=None, containers: bool = True, device=None):
         self._cols: tuple = tuple(columns)
         #: the device holding this store's device arrays (dense view,
         #: container packs); None: JAX's default device
@@ -378,6 +379,7 @@ class TileStore:
         self._device_packs: tuple | None = None  # jnp pack mirrors + sentinels
         self._storage_words_cell: np.ndarray | None = None
         self._dense = dense  # optional cached jnp uint32[N, n_words]
+        self._planes = planes  # optional cached plane view (:meth:`planes`)
         # bit-level metadata (RUN tags, runcounts): computed on first access
         self._refined_classes: np.ndarray | None = None
         self._col_stats: tuple | None = None
@@ -638,9 +640,12 @@ class TileStore:
     @classmethod
     def from_packed(cls, columns, *, tile_words: int = 64, r: int | None = None,
                     containers: bool = True) -> "TileStore":
-        """Build from packed bitmaps uint32[N, n_words] (device or host)."""
-        dev = jnp.asarray(columns, WORD_DTYPE)
-        arr = np.asarray(jax.device_get(dev), dtype=np.uint32)
+        """Build from packed bitmaps uint32[N, n_words] (device or host).
+
+        The store keeps the words on the host only: the device views a
+        backend reads (:meth:`densify`, :meth:`planes`) are uploaded from
+        them when first asked for."""
+        arr = np.asarray(jax.device_get(columns), dtype=np.uint32)
         if arr.ndim != 2:
             raise ValueError(f"expected uint32[N, n_words], got shape {arr.shape}")
         n, nw = arr.shape
@@ -652,7 +657,7 @@ class TileStore:
             _classify_column(padded[i], tile_words, containers=enabled)
             for i in range(n)
         ]
-        return cls(cols, tile_words=tile_words, n_words=nw, r=r, dense=dev,
+        return cls(cols, tile_words=tile_words, n_words=nw, r=r,
                    containers=enabled)
 
     @classmethod
@@ -746,6 +751,7 @@ class TileStore:
         store._storage_words_cell = None
         store._device_packs = None
         store._dense = None
+        store._planes = None
         store._refined_classes = None
         store._col_stats = None
         store._member_stats_cache = {}
@@ -772,15 +778,20 @@ class TileStore:
         -- and compressed, so query results fed back as virtual columns are
         stored in container form, not as dense words."""
         col = self._classify_row(packed_row)
-        dense = None
+        dense = planes = None
         if self._dense is not None:
             dense = jnp.concatenate(
                 [self._dense, self.put(jnp.asarray(packed_row, WORD_DTYPE))[None]],
                 axis=0,
             )
+        if self._planes is not None:
+            planes = jnp.concatenate(
+                [self._planes, self.put(self._row_planes(packed_row))], axis=0
+            )
         return TileStore(list(self._cols) + [col], tile_words=self.tile_words,
                          n_words=self.n_words, r=self.r, dense=dense,
-                         containers=self.containers, device=self.device)
+                         planes=planes, containers=self.containers,
+                         device=self.device)
 
     def replace(self, i: int, packed_row) -> "TileStore":
         """New store with column ``i`` swapped; only its tiles are reclassified
@@ -788,14 +799,23 @@ class TileStore:
         col = self._classify_row(packed_row)
         cols = list(self._cols)
         cols[int(i)] = col
-        dense = None
+        dense = planes = None
         if self._dense is not None:
             dense = self._dense.at[int(i)].set(
                 self.put(jnp.asarray(packed_row, WORD_DTYPE))
             )
+        if self._planes is not None:
+            planes = self._planes.at[int(i)].set(
+                self.put(self._row_planes(packed_row))[0]
+            )
         return TileStore(cols, tile_words=self.tile_words, n_words=self.n_words,
-                         r=self.r, dense=dense, containers=self.containers,
-                         device=self.device)
+                         r=self.r, dense=dense, planes=planes,
+                         containers=self.containers, device=self.device)
+
+    def _row_planes(self, packed_row) -> np.ndarray:
+        """One packed row as a one-column host plane view."""
+        row = np.asarray(jax.device_get(packed_row), np.uint32)
+        return host_planes(row.reshape(1, self.n_words))
 
     def apply_tile_updates(self, updates: dict, *, r: int | None = None
                            ) -> "TileStore":
@@ -843,7 +863,7 @@ class TileStore:
                 )
                 continue
             cols.append(self._respliced_column(old, upd, n_tiles_new, growth))
-        # dense view: dropped, rebuilt lazily from tiles on first densify()
+        # device views: dropped, rebuilt lazily from tiles on first use
         return TileStore(cols, tile_words=tw, n_words=nw_new, r=r_new,
                          containers=self.containers, device=self.device)
 
@@ -1104,6 +1124,17 @@ class TileStore:
             self._dense = self.put(self._padded_host()[:, : self.n_words])
         return self._dense
 
+    def planes(self) -> jax.Array:
+        """Plane view uint32[N, P, 8, 128] (cached) for the fused
+        bare-threshold kernel: each column a tile-aligned plane, padded
+        once here so that no query pads
+        (:func:`repro.kernels.threshold_ssum.host_planes`)."""
+        if self._planes is None:
+            self._planes = self.put(
+                host_planes(self._padded_host()[:, : self.n_words])
+            )
+        return self._planes
+
     def put(self, x) -> jax.Array:
         """``x`` as a device array on this store's device."""
         if self.device is None:
@@ -1111,15 +1142,18 @@ class TileStore:
         return jax.device_put(x, self.device)
 
     def on_device(self, device) -> "TileStore":
-        """This store with its device arrays (dense view, container packs)
-        on ``device``.  Host-side tiles, packs and statistics are shared;
-        device arrays not yet built are built there on first use."""
+        """This store with its device arrays (dense and plane views,
+        container packs) on ``device``.  Host-side tiles, packs and
+        statistics are shared; device arrays not yet built are built there
+        on first use."""
         if device is None or device == self.device:
             return self
         store = copy.copy(self)
         store.device = device
         if self._dense is not None:
             store._dense = jax.device_put(self._dense, device)
+        if self._planes is not None:
+            store._planes = jax.device_put(self._planes, device)
         store._device_packs = None
         store._dirty_dev = None
         store.__dict__.pop("_scan_plan_cache", None)  # holds device arrays

@@ -16,8 +16,8 @@ dsk, ...) bit-identically to a from-scratch rebuild, without merging:
   * ``dirty`` is the base packed dirty array with the patched tiles'
     words appended at the end; ``dirty_index`` redirects patched tiles
     there, so tiled gathers read patched words and never stale base rows;
-  * ``densify()`` scatters the patched tiles into the (cached) base dense
-    view in one device op;
+  * ``densify()`` and ``planes()`` scatter the patched tiles into the
+    base words on the host and upload the view once (cached);
   * ``member_stats`` / ``cardinalities`` fold the delta's popcount deltas
     in, so the planner prices the overlaid data, not the stale base.
 
@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bitmaps import n_words_for
+from repro.kernels.threshold_ssum import host_planes
 from repro.storage import (
     CONT_DENSE,
     CONT_NONE,
@@ -108,6 +109,7 @@ class OverlayStore:
         self._dirty_np_cache: np.ndarray | None = None
         self._dirty_dev = None
         self._dense = None
+        self._planes = None
         self._solid_cache: TileStore | None = None
         self._member_stats_cache: dict = {}
         self._card_cache: tuple | None = None
@@ -220,16 +222,10 @@ class OverlayStore:
         return self.base.gather_events(cols, tiles)
 
     # -- dense-path surface ------------------------------------------------
-    def densify(self) -> jax.Array:
-        """Dense view with the patched tiles scattered in.
-
-        Built host-side from the base tiles (vectorised row scatter into
-        the padded ``[n, n_tiles, tile_words]`` layout, one upload) --
-        device-side scatters recompile per delta shape, which dominated
-        wall time for large deltas.  Cached per overlay.
-        """
-        if self._dense is not None:
-            return self._dense
+    def _host_words(self) -> np.ndarray:
+        """Host uint32[n, n_words] of base ⊕ delta: a vectorised row
+        scatter of the base tiles and the patched ones into the padded
+        ``[n, n_tiles, tile_words]`` layout."""
         tw = self.tile_words
         padded = np.zeros((self.n, self.n_tiles, tw), np.uint32)
         bt = self.base.n_tiles
@@ -239,10 +235,25 @@ class OverlayStore:
         for col, tmap in self._patched.items():
             ts = np.fromiter(tmap, np.int64, len(tmap))
             padded[col, ts] = np.stack(list(tmap.values()))
-        self._dense = jnp.asarray(
-            padded.reshape(self.n, -1)[:, : self.n_words]
-        )
+        return padded.reshape(self.n, -1)[:, : self.n_words]
+
+    def densify(self) -> jax.Array:
+        """Dense view with the patched tiles scattered in.
+
+        Built host-side (:meth:`_host_words`, one upload) -- device-side
+        scatters recompile per delta shape, which dominated wall time for
+        large deltas.  Cached per overlay.
+        """
+        if self._dense is None:
+            self._dense = jnp.asarray(self._host_words())
         return self._dense
+
+    def planes(self) -> jax.Array:
+        """Plane view of base ⊕ delta for the fused bare-threshold kernel
+        (``TileStore.planes``), from the same host words.  Cached."""
+        if self._planes is None:
+            self._planes = jnp.asarray(host_planes(self._host_words()))
+        return self._planes
 
     def column(self, i: int) -> jax.Array:
         return self.densify()[int(i)]

@@ -276,3 +276,45 @@ def test_server_info_reports_plan_memo_counters():
     server.serve_many([Interval(2, 4)])
     assert "plan_memo" in server.info()
     assert set(server.info()["plan_memo"]) >= {"hits", "misses", "entries"}
+
+
+# -- fused bare thresholds read their members in place ---------------------
+
+def test_served_fused_bare_thresholds_read_store_planes(monkeypatch):
+    """Served fused bare thresholds read their members from the store's
+    plane view: the 2-D dense view is never built, every execution counts
+    as a plane read, each dispatch span says so, and nothing is gathered."""
+    import repro.obs as obs
+    import repro.query.index as qindex
+    from repro.core.bitmaps import unpack
+    from repro.query import bare_members_info
+
+    monkeypatch.setattr(qindex, "_fused_available", lambda: True)
+    bits = _bits(n=16, r=4096 + 37, seed=7, density=0.4)
+    idx = BitmapIndex.from_dense(bits, names=_names(16))
+    members = [(3, 9, 1, 14), (0, 5, 6, 7, 8, 2), (11, 4, 1), (15, 12, 10, 13, 3)]
+    queries = [Threshold(len(m) // 2 + 1, over=tuple(f"s{i}" for i in m))
+               for m in members]
+    assert [idx.explain(q).algorithm for q in queries] == ["fused"] * len(queries)
+    before = bare_members_info()
+    server = QueryServer(idx, window=0)
+    spans = []
+    obs.enable()
+    try:
+        for q, m in zip(queries, members):
+            fut = server.submit(q)
+            server.pump()
+            got = np.asarray(unpack(fut.result(), idx.r))
+            np.testing.assert_array_equal(got, bits[list(m)].sum(0) >= q.t)
+            spans += [s for s in obs.last_trace().iter() if s.name == "dispatch"]
+    finally:
+        obs.disable()
+    after = bare_members_info()
+    assert server.info()["executed"] == len(queries)
+    assert after["planes"] - before["planes"] == len(queries)
+    assert after["rows"] == before["rows"]
+    assert server.info()["bare_members"] == after
+    assert [s.attrs["members"] for s in spans] == ["planes"] * len(queries)
+    assert all(s.attrs["dirty_words_gathered"] == 0 for s in spans)
+    assert idx.last_info["dirty_words_gathered"] == 0
+    assert idx.store._dense is None

@@ -301,3 +301,19 @@ assert eng.draining_slots() == []
 print("sharded serve OK")
 """
     )
+
+
+def test_fused_bare_threshold_reads_each_shards_planes(mixed_index):
+    """The fused kernel reads each shard's members in place from that
+    shard's plane view -- same words as the rows path (``ssum`` gathers
+    member rows), nothing gathered."""
+    bits, idx, sidx = mixed_index
+    names = idx.names
+    q = Threshold(3, over=tuple(names[i] for i in (7, 2, 5, 0, 9)))
+    want = np.asarray(idx.execute(q, backend="ssum"))
+    assert idx.last_info["members"] == "rows"
+    got = np.asarray(sidx.execute(q, backend="fused").gather())
+    np.testing.assert_array_equal(got, want)
+    info = sidx.last_info
+    assert info["members"] == "planes" and info["dirty_words_gathered"] == 0
+    assert [i["members"] for i in info["per_shard"]] == ["planes"] * N_SHARDS

@@ -56,6 +56,29 @@ def _t_for(alg, n):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("n_shards", [None, 3])
+def test_fused_bare_threshold_reads_overlay_planes(n_shards):
+    """Over base + delta the fused kernel reads the overlay's plane view:
+    the words match the rows path and the rebuild oracle, mutated tiles
+    included."""
+    bits = _bits(6, 3 * SPAN + 517, density=0.3, seed=4)
+    s = _stream(bits, n_shards=n_shards)
+    pos = [0, 33, SPAN + 7, 3 * SPAN + 500]
+    s.set_bits("c4", pos)
+    s.clear_bits("c1", np.arange(0, 3 * SPAN, 5))
+    bits = bits.copy()
+    bits[4, pos] = True
+    bits[1, np.arange(0, 3 * SPAN, 5)] = False
+    q = Threshold(2, over=("c4", "c1", "c3", "c0"))
+    got = _result(s, q, backend="fused")
+    np.testing.assert_array_equal(got, _result(s, q, backend="ssum"))
+    np.testing.assert_array_equal(got, bits[[4, 1, 3, 0]].sum(0) >= 2)
+    idx = s.index()
+    _result(s, q, backend="fused")
+    assert idx.last_info["members"] == "planes"
+    assert idx.last_info["dirty_words_gathered"] == 0
+
+
 class TestMutationKinds:
     N, R = 5, 4 * SPAN + 517  # partial final tile by construction
 

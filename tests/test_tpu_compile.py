@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,28 @@ def test_fused_kernel_compiles_at_real_width(one_chip, queries):
         lambda b: run_circuit_pallas(b, circuit, interpret=False), cols
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [13, 31])
+def test_plane_kernel_reads_members_in_place(one_chip, m):
+    """The bare-threshold kernel over the plane view of 64 columns of
+    2**28 rows: the member slots are a scalar prefetch, so the compiled
+    program is the kernel alone -- no gather, pad or copy of the members,
+    no temporary buffer, and the flat result a bitcast of its output."""
+    from repro.kernels.threshold_ssum import threshold_pallas
+
+    n_words = 1 << 23
+    planes = jax.ShapeDtypeStruct((N_STORES, n_words // 1024, 8, 128), jnp.uint32,
+                                  sharding=one_chip)
+    slots = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    compiled = threshold_pallas.lower(
+        planes, slots, m // 2, n_words=n_words, interpret=False
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\b(gather|pad|copy)(-start)?\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    assert compiled.memory_analysis().output_size_in_bytes == n_words * 4
 
 
 @pytest.mark.parametrize(
