@@ -193,9 +193,9 @@ def shard_sweep(smoke: bool = False) -> list:
     for s in SHARD_COUNTS:
         mesh = None
         if s == len(jax.devices()) > 1:
-            from repro.launch.mesh import make_host_mesh
+            from repro.dist.mesh import make_host_mesh
 
-            mesh = make_host_mesh(data=s, model=1)
+            mesh = make_host_mesh(data=s)
         sidx = idx.shard(mesh=mesh, n_shards=s)
         t = _time(lambda: sidx.execute(q).gather().block_until_ready())
         info = sidx.last_info

@@ -28,8 +28,6 @@ Prints ``name,value,derived`` CSV lines.  Sections:
               DivideSkip / WHEAP) at the same T, adaptive top-k, window
               refresh words-touched vs the touched-tiles bound
               (repro.search; smoke sizes, writes BENCH_search.json)
-  roofline -- three-term roofline per dry-run cell (deliverable g; requires
-              artifacts/dryrun from ``python -m repro.launch.dryrun``)
 """
 from __future__ import annotations
 
@@ -41,7 +39,7 @@ def main() -> None:
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    sections = sys.argv[1:] or ["table5", "table7", "fig3", "table10", "heatmap", "kernel", "weighted", "query", "stream", "persist", "serve", "obs", "search", "roofline"]
+    sections = sys.argv[1:] or ["table5", "table7", "fig3", "table10", "heatmap", "kernel", "weighted", "query", "stream", "persist", "serve", "obs", "search"]
     failures = 0
     for section in sections:
         print(f"# --- {section} ---")
@@ -98,12 +96,6 @@ def main() -> None:
                 from benchmarks import search_bench as mod
 
                 rows = mod.run(smoke=True)
-            elif section == "roofline":
-                from benchmarks import roofline as mod
-
-                rows, table = mod.run()
-                if table:
-                    print(f"# roofline table -> {mod.write_markdown(table)}")
             else:
                 raise ValueError(f"unknown section {section}")
             for name, val, extra in rows:

@@ -503,12 +503,12 @@ def run_sharded(seed: int, log2_rows: int, n_chips: int) -> dict:
     """The sharded path over ``n_chips`` devices, checked against the
     numpy reference and the unsharded index; returns the modes seen per
     query set."""
-    from repro.launch.mesh import make_host_mesh
+    from repro.dist.mesh import make_host_mesh
 
     devices = jax.devices()[:n_chips]
     if len(devices) < n_chips:
         raise RuntimeError(f"{n_chips} devices needed, {len(jax.devices())} found")
-    mesh = make_host_mesh(data=n_chips, model=1)
+    mesh = make_host_mesh(data=n_chips)
     r = n_chips << log2_rows
     t0 = time.perf_counter()
     cols, names, uni, clu = make_columns(seed, log2_rows + (n_chips - 1).bit_length())

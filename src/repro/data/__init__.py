@@ -1,2 +1,1 @@
 from .paper_datasets import clustered_set, similarity_query, synthetic_dataset, uniform_set
-from .pipeline import DataConfig, arch_batch, lm_batch, lm_batches

@@ -1,33 +1,22 @@
-"""Distributed substrate: sharding context, gradient compression, pipeline,
-and the row-sharded query engine.
+"""The row-sharded query engine.
 
-``context`` carries the active :class:`ShardingRules` so model code can
-express sharding with *logical* axis names (``batch``, ``heads``...) and run
-unchanged both unsharded (unit tests) and SPMD-partitioned (train/serve).
-``compression`` implements the int8 ring all-reduce with error feedback;
-``pipeline`` the microbatch pipeline schedule over a mesh axis; ``query``
-partitions a ``BitmapIndex``'s row space into per-device shards with
-per-shard query planning (``BitmapIndex.shard(mesh)`` is the front door).
+``query`` partitions a ``BitmapIndex``'s row space into per-device shards
+with per-shard query planning (``BitmapIndex.shard(mesh)`` is the front
+door); ``mesh`` builds the device mesh those shards run on.
 """
 
-from .context import ShardingRules, axis_size, constrain, get_rules, use_rules
+from .query import (
+    ShardedBitmapIndex,
+    ShardedPlan,
+    ShardedResult,
+    ShardedTileStore,
+    shard_boundaries,
+)
 
-# The sharded query engine re-exports are lazy (PEP 562): model/train code
-# imports repro.dist.context at module level and must not drag the whole
-# query/storage/planner stack in with it -- the dist -> query dependency
-# only materialises when somebody actually reaches for the sharded engine.
-_QUERY_EXPORTS = (
+__all__ = [
     "ShardedBitmapIndex",
     "ShardedPlan",
     "ShardedResult",
     "ShardedTileStore",
     "shard_boundaries",
-)
-
-
-def __getattr__(name):
-    if name in _QUERY_EXPORTS:
-        from . import query
-
-        return getattr(query, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+]

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core.bitmaps import WORD_DTYPE, packed_tail_mask
 from repro.core.planner import Plan, plan_query
+from repro.dist.mesh import mesh_axis_sizes
 from repro.storage import TileStore
 
 __all__ = [
@@ -329,8 +330,6 @@ class ShardedPlan:
 
 
 def _axis_size(mesh, axis: str) -> int:
-    from repro.launch.mesh import mesh_axis_sizes
-
     sizes = mesh_axis_sizes(mesh)
     if axis not in sizes:
         raise ValueError(f"mesh has no axis {axis!r}; axes: {tuple(sizes)}")

@@ -1,14 +1,11 @@
-"""Serving layer: the model-decode slot engine and the query front-end.
+"""Serving layer: the query front-end.
 
-  * :mod:`~repro.serve.engine` -- continuous-batching decode engine whose
-    slot-selection state is a streaming bitmap index;
-  * :mod:`~repro.serve.frontend` -- :class:`QueryServer`, the
-    high-throughput multi-client query front-end: shape-bucketed
-    micro-batching over ``execute_many``, semantic request deduplication,
-    a version-keyed result cache invalidated by streaming version bumps,
-    bounded-queue admission control, and planner-calibration feedback.
+:mod:`~repro.serve.frontend` holds :class:`QueryServer`, the
+high-throughput multi-client query front-end: shape-bucketed
+micro-batching over ``execute_many``, semantic request deduplication, a
+version-keyed result cache invalidated by streaming version bumps,
+bounded-queue admission control, and planner-calibration feedback.
 """
-from .engine import Request, ServeEngine
 from .frontend import Overloaded, QueryServer, shape_bucket
 
-__all__ = ["Request", "ServeEngine", "Overloaded", "QueryServer", "shape_bucket"]
+__all__ = ["Overloaded", "QueryServer", "shape_bucket"]

@@ -1,44 +1,14 @@
-"""Data pipeline determinism + paper dataset generators + planner rules."""
+"""Paper dataset generators + planner rules."""
 import numpy as np
 
-from repro.configs import get_config
 from repro.core.bitmaps import cardinality
 from repro.core.planner import plan_threshold
-from repro.data import DataConfig, arch_batch, lm_batch
 from repro.data.paper_datasets import (
     clustered_set,
     similarity_query,
     synthetic_dataset,
     uniform_set,
 )
-
-
-def test_lm_batch_deterministic_per_step():
-    dc = DataConfig(vocab=1000, batch=4, seq=32, seed=7)
-    a = lm_batch(dc, 5)
-    b = lm_batch(dc, 5)
-    c = lm_batch(dc, 6)
-    assert np.array_equal(a["tokens"], b["tokens"])  # restart replays
-    assert not np.array_equal(a["tokens"], c["tokens"])
-
-
-def test_lm_batch_host_sharding():
-    full = DataConfig(vocab=100, batch=8, seq=16, seed=1)
-    h0 = DataConfig(vocab=100, batch=8, seq=16, seed=1, n_hosts=2, host_id=0)
-    assert lm_batch(h0, 0)["tokens"].shape[0] == 4
-    assert lm_batch(full, 0)["tokens"].shape[0] == 8
-
-
-def test_arch_batch_shapes():
-    for arch in ("internvl2-26b", "hubert-xlarge", "qwen3-1.7b"):
-        cfg = get_config(arch, reduced=True)
-        b = arch_batch(cfg, 2, 32, "train")
-        assert b["labels"].shape == (2, 32)
-        if cfg.frontend == "vision":
-            assert b["tokens"].shape[1] == 32 - cfg.frontend_tokens
-            assert float(b["mask"][:, : cfg.frontend_tokens].sum()) == 0.0
-        if cfg.frontend == "audio":
-            assert b["features"].shape == (2, 32, cfg.frontend_dim)
 
 
 def test_synthetic_dataset_paper_5_3():

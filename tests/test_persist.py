@@ -13,7 +13,6 @@ lives in ``test_persist_fuzz.py``):
   * PagedTileStore answers bit-identically while keeping packs host-side
   * the committed golden snapshot keeps loading AND regenerating
     byte-identically (format-stability contract)
-  * ServeEngine warm-starts its slot index from a checkpoint
 """
 import importlib.util
 import os
@@ -434,33 +433,3 @@ def test_golden_fixture_bytes_are_stable(tmp_path):
     regen = tmp_path / "regen.bmsnap"
     make_golden.write(str(regen))
     assert regen.read_bytes() == Path(make_golden.FIXTURE).read_bytes()
-
-
-# -- serve warm start ------------------------------------------------------
-
-def test_serve_engine_warm_start(tmp_path):
-    jax = pytest.importorskip("jax")
-    from repro.configs import get_config
-    from repro.models import init_params
-    from repro.serve import Request, ServeEngine
-
-    cfg = get_config("qwen3-1.7b", reduced=True)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = ServeEngine(cfg, params, batch_slots=4, max_seq=64)
-    for i in range(3):
-        assert eng.submit(Request(rid=i, prompt=[i + 1, 2], max_new=2))
-    eng.step()
-    d = tmp_path / "slots"
-    eng.snapshot_slot_index(d)
-    eng.step()  # completes all three -> WAL-only tail frees the slots
-    assert eng.free_slots() == [0, 1, 2, 3]
-
-    eng2 = ServeEngine(cfg, params, batch_slots=4, max_seq=64)
-    assert eng2.warm_start_slot_index(d)
-    assert eng2.free_slots() == eng.free_slots()
-    assert eng2.draining_slots() == eng.draining_slots()
-    assert eng2._occ_now == eng._occ_now
-    # universe mismatch refuses cleanly
-    eng3 = ServeEngine(cfg, params, batch_slots=8, max_seq=64)
-    assert not eng3.warm_start_slot_index(d)
-    assert not eng3.warm_start_slot_index(tmp_path / "nope")

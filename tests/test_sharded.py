@@ -2,10 +2,10 @@
 
 Oracle parity: every backend on a ShardedTileStore must be bit-identical
 to the unsharded TileStore result -- including a shard with ZERO dirty
-tiles and a partial final tile in the last shard.  Mesh-dependent paths
-(shard_map, sharded serve slot selection) run in-process when 8 XLA
-devices exist (the CI tier1-sharded job forces them) and always via a
-subprocess with XLA_FLAGS set, like test_dist.
+tiles and a partial final tile in the last shard.  The mesh-dependent
+path (shard_map) runs in-process when 8 XLA devices exist (the CI
+tier1-sharded job forces them) and always via a subprocess with
+XLA_FLAGS set.
 """
 import os
 import subprocess
@@ -208,11 +208,11 @@ needs_mesh = pytest.mark.skipif(
 
 @needs_mesh
 def test_shard_map_path_in_process():
-    from repro.launch.mesh import make_host_mesh
+    from repro.dist.mesh import make_host_mesh
 
     bits = np.random.default_rng(5).random((10, 16 * TILE_BITS + 300)) < 0.3
     idx = BitmapIndex.from_dense(jnp.asarray(bits))
-    mesh = make_host_mesh(data=8, model=1)
+    mesh = make_host_mesh(data=8)
     sidx = idx.shard(mesh=mesh)
     q = And(Interval(2, 6), Not(Threshold(9)))
     res = sidx.execute(q)
@@ -230,7 +230,7 @@ def test_shard_map_acceptance_subprocess():
         """
 import numpy as np, jax, jax.numpy as jnp
 assert len(jax.devices()) == 8
-from repro.launch.mesh import make_host_mesh
+from repro.dist.mesh import make_host_mesh
 from repro.query import BitmapIndex, Threshold
 TILE_BITS = 64 * 32
 rng = np.random.default_rng(0)
@@ -251,7 +251,7 @@ for i in range(n):
             else:
                 bits[i, lo:hi] = rng.random(hi - lo) < 0.35
 idx = BitmapIndex.from_dense(jnp.asarray(bits))
-mesh = make_host_mesh(data=8, model=1)
+mesh = make_host_mesh(data=8)
 sidx = idx.shard(mesh=mesh)
 assert sidx.n_shards == 8
 
@@ -271,34 +271,6 @@ assert sdense.last_info["mode"] == "shard_map", sdense.last_info
 assert np.array_equal(np.asarray(res.gather()),
                       np.asarray(dense_idx.execute(Threshold(4), backend="ssum")))
 print("sharded acceptance OK")
-"""
-    )
-
-
-def test_serve_engine_sharded_slots_subprocess():
-    """Serve slot selection through the sharded path on an 8-device mesh."""
-    _run(
-        """
-import jax, numpy as np
-from repro.configs import get_config
-from repro.launch.mesh import make_host_mesh
-from repro.models import init_params
-from repro.serve import Request, ServeEngine
-
-assert len(jax.devices()) == 8
-cfg = get_config("qwen3-1.7b", reduced=True)
-params = init_params(cfg, jax.random.PRNGKey(0))
-mesh = make_host_mesh(data=8, model=1)
-eng = ServeEngine(cfg, params, batch_slots=256, max_seq=32, mesh=mesh)
-from repro.dist.query import ShardedBitmapIndex
-sidx = eng.slot_index()
-assert isinstance(sidx, ShardedBitmapIndex), type(sidx)
-assert sidx.n_shards == 8, sidx.n_shards
-assert eng.free_slots() == list(range(256))
-assert eng.submit(Request(rid=0, prompt=[1, 2], max_new=2))
-assert eng.free_slots() == list(range(1, 256))
-assert eng.draining_slots() == []
-print("sharded serve OK")
 """
     )
 
